@@ -32,7 +32,8 @@ Schema (``neo-bench-trend/v6``; documented in ``benchmarks/README.md``):
   busy / overlap / bubble / swap-hidden / plan-ahead accounting (GATED
   true: the trace is a standing audit of every other gated number);
 * ``sharded.*`` (v5) — the tensor-parallel A/B (``engine_sharded.py``,
-  TP=1 vs TP=2 on a fake-device CPU mesh): ``tp2_bitwise_ok`` (GATED:
+  TP=1 vs TP=2 in this process, so on the CPU start it with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=8``): ``tp2_bitwise_ok`` (GATED:
   gather-TP may never change greedy outputs), ``swap_bytes_equal`` and
   ``stream_split_exact`` (GATED: per-shard copy streams must partition
   the TP=1 byte totals exactly), plus the recorded per-shard byte split;
@@ -57,6 +58,7 @@ import os
 import sys
 
 from benchmarks.common import FIG_DIR, HERE
+from repro.launch.compile_cache import setup_compile_cache
 
 SCHEMA = "neo-bench-trend/v6"
 REPO_ROOT = os.path.dirname(HERE)
@@ -253,6 +255,7 @@ def gate(summary: dict, baseline: dict) -> int:
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=12,
                     help="requests per engine smoke run")

@@ -20,6 +20,7 @@ from repro.config import EngineConfig
 from repro.configs import get_smoke_config
 from repro.core.engine import EngineStats, NeoEngine
 from repro.core.transfer import TransferStats
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.api import get_model
 from repro.serving.traces import get_trace
 
@@ -423,6 +424,7 @@ def run_mixed_lane_section(n: int = 6) -> Tuple[int, dict]:
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=24)
     ap.add_argument("--microbatch-only", action="store_true",

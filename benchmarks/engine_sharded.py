@@ -1,17 +1,22 @@
-"""Tensor-parallel serving A/B: TP=2 on a fake-device CPU mesh vs TP=1.
+"""Tensor-parallel serving A/B: TP=2 vs TP=1 over this process's devices.
 
 Gather-TP column-shards the QKV/gate/up projections and all-gathers (a pure
 concat) before the replicated O/down projections, so every cross-shard
 combine is reduction-free — greedy decode at TP=2 must be BITWISE identical
 to TP=1, and the per-shard copy streams must partition the swap bytes
-exactly.  This smoke runs both engines on the same swap-heavy trace inside
-one subprocess (the parent process keeps its real single-device backend;
-the child gets ``--xla_force_host_platform_device_count``) and gates:
+exactly.  This smoke runs both engines on the same swap-heavy trace, in this
+process and over the devices it already holds (a child process could not
+reach a chip this process holds), and gates:
 
 * ``tp2_bitwise_ok`` — greedy outputs identical across TP=1/TP=2;
 * ``swap_bytes_equal`` — PCIe byte totals (out + in) identical;
 * ``stream_split`` — TP=2 records per-shard copy-stream bytes
   (``out0``/``out1``/...) that sum exactly to the direction totals.
+
+On the CPU, start it with two or more host devices::
+
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.engine_sharded
 
 Results land in ``experiments/figures/engine_sharded.json`` and feed the
 ``sharded`` section of ``bench_trend``'s summary.
@@ -20,31 +25,28 @@ Results land in ``experiments/figures/engine_sharded.json`` and feed the
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
+import time
+
+import jax
+import numpy as np
 
 from benchmarks.common import print_table, save_json
-
-_CHILD = """
-import json
-import numpy as np
 from repro.config import EngineConfig
 from repro.configs import get_smoke_config
 from repro.core.engine import NeoEngine
 from repro.core.request import RequestState
+from repro.launch.compile_cache import setup_compile_cache
 
-cfg = get_smoke_config('qwen3-0.6b')
 
-def run(tp, n):
+def _run_engine(tp: int, n: int) -> dict:
+    cfg = get_smoke_config("qwen3-0.6b")
     ecfg = EngineConfig(device_pool_pages=10, host_pool_pages=128,
-                        max_batch_tokens=1024, policy='neo', tp=tp)
+                        max_batch_tokens=1024, policy="neo", tp=tp)
     eng = NeoEngine(cfg, ecfg)
     rng = np.random.default_rng(0)
     rids = [eng.submit(rng.integers(0, cfg.vocab_size, size=24 + 3 * i).tolist(), 12)
             for i in range(n)]
-    import time
     t0 = time.perf_counter()
     for _ in range(600):
         eng.step()
@@ -54,35 +56,23 @@ def run(tp, n):
     toks = sum(len(eng.requests[r].out_tokens) for r in rids)
     ts = eng.transfer.stats
     res = {
-        'outputs': {str(r): list(map(int, eng.requests[r].out_tokens)) for r in rids},
-        'swap_bytes': int(eng.pool.swap_bytes),
-        'bytes_out': int(ts.bytes_out),
-        'bytes_in': int(ts.bytes_in),
-        'bytes_by_stream': {k: int(v) for k, v in ts.bytes_by_stream.items()},
-        'tok_s': toks / max(wall, 1e-9),
+        "outputs": {str(r): list(map(int, eng.requests[r].out_tokens)) for r in rids},
+        "swap_bytes": int(eng.pool.swap_bytes),
+        "bytes_out": int(ts.bytes_out),
+        "bytes_in": int(ts.bytes_in),
+        "bytes_by_stream": {k: int(v) for k, v in ts.bytes_by_stream.items()},
+        "tok_s": toks / max(wall, 1e-9),
     }
     eng.close()
     return res
 
-n = %(n)d
-out = {'tp1': run(1, n), 'tp2': run(2, n)}
-print('RESULT ' + json.dumps(out))
-"""
 
-
-def run(n: int = 6, devices: int = 8) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, "-c", _CHILD % {"n": n}],
-                          env=env, capture_output=True, text=True, timeout=1200)
-    if proc.returncode != 0:
-        raise RuntimeError(f"sharded smoke subprocess failed:\n"
-                           f"{proc.stdout}\n{proc.stderr}")
-    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
-    ab = json.loads(line[len("RESULT "):])
-    tp1, tp2 = ab["tp1"], ab["tp2"]
+def run(n: int = 6) -> dict:
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            "the TP=2 A/B needs >= 2 devices in this process; on the CPU "
+            "start with XLA_FLAGS=--xla_force_host_platform_device_count=8")
+    tp1, tp2 = _run_engine(1, n), _run_engine(2, n)
     streams = tp2["bytes_by_stream"]
     out_split = {k: v for k, v in streams.items() if k.startswith("out")}
     in_split = {k: v for k, v in streams.items() if k.startswith("in")}
@@ -107,6 +97,7 @@ def run(n: int = 6, devices: int = 8) -> dict:
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=6, help="requests per A/B run")
     args = ap.parse_args(argv)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import print_table, save_json
+from repro.launch.compile_cache import setup_compile_cache
 
 
 def timeit(fn, *args, iters=5):
@@ -101,6 +102,7 @@ def bench_mamba2():
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     rows = [bench_paged_decode(), bench_flash_prefill(), bench_rwkv6(), bench_mamba2()]
     print("=== Pallas kernels: ref wall-time (this host), interpret-mode max|Δ| vs oracle, VMEM/block ===")
     print_table(["kernel", "ref ms", "pallas max err", "VMEM/block", "tiling"], rows)

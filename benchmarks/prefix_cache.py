@@ -27,6 +27,7 @@ from collections import defaultdict
 import jax
 
 from benchmarks.common import print_table, save_json
+from repro.launch.compile_cache import setup_compile_cache
 from repro.config import EngineConfig
 from repro.configs import get_smoke_config
 from repro.core.engine import NeoEngine
@@ -188,6 +189,7 @@ def run_host_serving(conversations, warmup, *, params, cfg,
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=12, help="total turns")
     ap.add_argument("--turns", type=int, default=4, help="turns/conversation")

@@ -201,8 +201,10 @@ class NeoEngine:
         *,
         params: Optional[Dict[str, Any]] = None,
         rng: Optional[jax.Array] = None,
-        kernel_impl: str = "ref",
+        kernel_impl: Optional[str] = None,
     ):
+        """``kernel_impl`` overrides the platform's choice of device decode
+        attention ("ref", "pallas" or "interpret"); tests only."""
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.model = get_model(cfg)

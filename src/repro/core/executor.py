@@ -7,7 +7,8 @@ Two executors:
 
   - **batch-0** (device rows + ``cpu0`` host rows): ONE jitted graph per
     (rows, pages) bucket — device rows attend via the paged-attention kernel
-    (Pallas on TPU, jnp oracle here); its host rows detour through an
+    (the compiled Pallas kernel on TPU, the jnp
+    reference on the CPU); its host rows detour through an
     **ordered io_callback** to :class:`HostAttention` per layer (the
     JAX-native analogue of the paper's TrQKV → CPU-attn → TrO pipeline).
     Python kernel-launch overhead is paid once per iteration (the paper's §4
@@ -57,7 +58,6 @@ from repro.distributed.sharding import (
     ShardingContext,
     activate,
     gather_tp_spec,
-    shard_map_nocheck,
     tp_allgather,
     tp_axis,
     tp_body,
@@ -68,45 +68,6 @@ from repro.models.moe import moe_apply
 from repro.models.transformer import DenseLM, project_qkv
 
 Params = Dict[str, Any]
-
-
-def _patch_io_callback_operand_roundtrip() -> None:
-    """Work around a host-callback self-deadlock on low-core machines.
-
-    jax 0.4.x's ``io_callback_impl`` round-trips the runtime-delivered
-    numpy operands through ``jax.device_put`` before invoking the Python
-    callback.  The XLA CPU custom-call runs the callback inline on the
-    client's async-dispatch pool thread; ``device_put`` enqueues an async
-    host-to-device copy on that same pool, so on a single-threaded client
-    (nproc==1 containers) the callback blocks forever materializing its
-    own operands (``int(layer)`` / ``np.asarray(q)``) while the only pool
-    thread is parked inside the callback — the whole graph deadlocks.
-
-    Every callback in this repo consumes plain numpy, so the round-trip
-    buys nothing: replace the impl with a straight pass-through.  The CPU
-    lowering closure resolves ``io_callback_impl`` as a module global at
-    call time, so already-compiled graphs pick the patch up too.  Guarded
-    to the known-affected 0.4.x line; newer jax runs unpatched.
-    """
-    if not jax.__version__.startswith("0.4."):
-        return
-    try:
-        from jax import tree_util
-        from jax._src import callback as _jcb
-    except ImportError:  # internal layout moved; leave jax alone
-        return
-    if getattr(_jcb, "_neo_io_callback_patched", False):
-        return
-
-    def _impl(*args, result_avals, callback, sharding, ordered):
-        del result_avals, sharding, ordered
-        return tree_util.tree_map(np.asarray, callback(*args))
-
-    _jcb.io_callback_impl = _impl
-    _jcb._neo_io_callback_patched = True
-
-
-_patch_io_callback_operand_roundtrip()
 
 
 def _bucket(n: int, minimum: int = 8) -> int:
@@ -120,16 +81,19 @@ class PagedExecutor:
     """Paged decode + bucketed prefill for decoder-only attention families."""
 
     def __init__(self, model: DenseLM, params: Params, pool: DualPool,
-                 host_attn: HostAttention, *, impl: str = "ref",
-                 interpret: bool = True, host_lanes: int = 2,
-                 tp: int = 1, mesh=None):
+                 host_attn: HostAttention, *, impl: Optional[str] = None,
+                 host_lanes: int = 2, tp: int = 1, mesh=None):
         self.model = model
         self.cfg: ArchConfig = model.cfg
         self.params = params
         self.pool = pool
         self.host = host_attn
-        self.impl = impl
-        self.interpret = interpret
+        # Device decode attention: the platform's implementation unless a
+        # test overrides it; "interpret" runs the Pallas kernel in interpret
+        # mode (CPU tests only).
+        self.interpret = impl == "interpret"
+        self.impl = "pallas" if self.interpret else (
+            impl or paged_ops.platform_impl())
         self.page = pool.page_size
         # --- gather-TP (reduction-free tensor parallelism) ---------------
         # Column-shard QKV / MLP-up over the mesh "model" axis, keep O /
@@ -384,8 +348,8 @@ class PagedExecutor:
                                           dev_lens, is_host, page_ids,
                                           offsets, pool_k, pool_v)
 
-        wrapped = shard_map_nocheck(
-            step, mesh=self.mesh,
+        wrapped = jax.shard_map(
+            step, mesh=self.mesh, check_vma=False,
             in_specs=(self._tp_param_specs, P(), P(), P(), P(), P(), P(), P(),
                       kv_spec, kv_spec),
             out_specs=(P(), kv_spec, kv_spec),
@@ -660,8 +624,8 @@ class PagedExecutor:
                 )
                 return logits, cache["k"], cache["v"]
 
-        wrapped = shard_map_nocheck(
-            body, mesh=self.mesh,
+        wrapped = jax.shard_map(
+            body, mesh=self.mesh, check_vma=False,
             in_specs=(self._tp_param_specs, P(), P()),
             out_specs=(P(), kv_spec, kv_spec),
         )
@@ -923,8 +887,8 @@ class PagedExecutor:
                     capacity=S, true_lens=true_lens,
                 )
 
-        wrapped = shard_map_nocheck(
-            body, mesh=self.mesh,
+        wrapped = jax.shard_map(
+            body, mesh=self.mesh, check_vma=False,
             in_specs=(self._tp_param_specs, P(), P(), P()),
             out_specs=(P(), kv_spec, kv_spec),
         )

@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.distributed.sharding import shard_map_nocheck
-
 Pytree = Any
 
 
@@ -66,8 +64,8 @@ def int8_allreduce_mean(
             s = jax.lax.psum(q.astype(jnp.int32), axes)
             return (s.astype(jnp.float32) * scale / n).astype(gl.dtype)
 
-        return shard_map_nocheck(
-            body, mesh=mesh,
+        return jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=P(*[None] * g.ndim),
             out_specs=P(*[None] * g.ndim),
         )(g)

@@ -29,23 +29,6 @@ from repro.config import ArchConfig
 
 Axes = Union[None, str, Tuple[str, ...]]
 
-# jax >= 0.5 exposes shard_map at the top level (kwarg check_vma); 0.4.x has
-# jax.experimental.shard_map.shard_map (kwarg check_rep).  One shim serves
-# every call site so replication checking stays off on both.
-_raw_shard_map = getattr(jax, "shard_map", None)
-if _raw_shard_map is None:
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
-    _CHECK_KWARG = "check_rep"
-else:
-    _CHECK_KWARG = "check_vma"
-
-
-def shard_map_nocheck(f, *, mesh, in_specs, out_specs):
-    return _raw_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KWARG: False},
-    )
-
 
 def default_rules(cfg: ArchConfig, mesh: Mesh) -> Dict[str, Axes]:
     axis_names = mesh.axis_names

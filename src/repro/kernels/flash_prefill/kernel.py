@@ -19,9 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; newer jax renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -111,7 +108,7 @@ def flash_prefill_pallas(
     window: int = 0,
     blk_q: int = 128,
     blk_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     B, S, H, hd = q.shape
     KV = k.shape[2]
@@ -151,7 +148,7 @@ def flash_prefill_pallas(
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -30,7 +30,7 @@ def flash_prefill(
     impl: str = "ref",
     blk_q: int = 128,
     blk_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     if impl == "ref":
         return _ref.flash_prefill_ref(q, k, v, causal=causal, window=window)

@@ -21,9 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; newer jax renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 CLIP = 60.0
 
 
@@ -93,7 +90,7 @@ def mamba2_ssd_pallas(
     state0: jnp.ndarray,  # [B, H, N, P]
     *,
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     B, T, H, P = x.shape
     N = Bm.shape[-1]
@@ -137,7 +134,7 @@ def mamba2_ssd_pallas(
             jax.ShapeDtypeStruct((B, H, Np, Pp), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Np, Pp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,7 +23,7 @@ def mamba2_ssd(
     *,
     impl: str = "scan",
     chunk: int = 64,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     if impl == "scan":
         return _ref.mamba2_ssd_ref(x, dt, A, Bm, Cm, D, state0)

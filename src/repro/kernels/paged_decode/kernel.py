@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; newer jax renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 
 
@@ -91,7 +88,7 @@ def paged_decode_pallas(
     block_tables: jnp.ndarray,  # [B, n_pages] int32
     lens: jnp.ndarray,  # [B] int32
     *,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     B, H, hd = q.shape
     P, page, KV, _ = k_pages.shape
@@ -124,7 +121,7 @@ def paged_decode_pallas(
         functools.partial(_decode_kernel, scale=scale, page=page, n_pages=n_pages),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, qpk, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,17 +1,25 @@
 """Jit'd wrapper for paged decode attention.
 
-Pads head_dim to a 128 multiple and q-heads-per-kv to a sublane multiple of 8
-before dispatching to the Pallas kernel; the jnp oracle path needs no padding.
+The implementation follows the platform: the compiled Pallas kernel on TPU,
+the jnp reference elsewhere (the CPU).  The Pallas path pads head_dim to a
+128 multiple and q-heads-per-kv to a sublane multiple of 8; the jnp
+reference needs no padding.
 """
 
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels.paged_decode import ref as _ref
+
+
+def platform_impl() -> str:
+    """The decode-attention implementation for the default backend."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 @partial(jax.jit, static_argnames=("impl", "interpret"))
@@ -22,9 +30,10 @@ def paged_decode_attention(
     block_tables: jnp.ndarray,  # [B, n_pages] int32
     lens: jnp.ndarray,  # [B] int32
     *,
-    impl: str = "ref",
-    interpret: bool = True,
+    impl: Optional[str] = None,
+    interpret: bool = False,
 ) -> jnp.ndarray:
+    impl = impl or platform_impl()
     if impl == "ref":
         return _ref.paged_decode_ref(q, k_pages, v_pages, block_tables, lens)
     if impl != "pallas":

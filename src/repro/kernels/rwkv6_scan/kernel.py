@@ -20,9 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<0.5 names this TPUCompilerParams; newer jax renamed it CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 CLIP = 60.0
 
 
@@ -97,7 +94,7 @@ def rwkv6_scan_pallas(
     state0: jnp.ndarray,  # [B, H, N, N]
     *,
     chunk: int = 16,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     B, T, H, N = r.shape
     assert T % chunk == 0, (T, chunk)
@@ -135,7 +132,7 @@ def rwkv6_scan_pallas(
             jax.ShapeDtypeStruct((B, H, Np, Np), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((Np, Np), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

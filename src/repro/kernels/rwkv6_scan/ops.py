@@ -28,7 +28,7 @@ def rwkv6_scan(
     *,
     impl: str = "scan",
     chunk: int = 16,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     if impl == "scan":
         return _ref.rwkv6_scan_ref(r, k, v, w, u, state0)

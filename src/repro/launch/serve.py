@@ -30,6 +30,7 @@ from repro.config import EngineConfig
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.core.engine import NeoEngine
 from repro.core.request import RequestState
+from repro.launch.compile_cache import setup_compile_cache
 from repro.obs.reconcile import reconcile
 from repro.serving.metrics import RequestRecord, ServeMetrics
 from repro.serving.traces import get_trace, save_trace
@@ -296,6 +297,7 @@ def run_sustained(*, arch: str = "qwen3-0.6b", smoke: bool = True,
 
 
 def main(argv=None) -> int:
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b", choices=ARCH_NAMES)
     ap.add_argument("--smoke", action="store_true",

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import shard
-from repro.distributed.sharding import current_context, shard_map_nocheck
+from repro.distributed.sharding import current_context
 
 NEG_INF = -1e30
 
@@ -312,9 +312,10 @@ def decode_attention_blocksharded(
         out = (num / jnp.maximum(den, 1e-30)[..., None]).astype(q.dtype)
         return out, kc, vc
 
-    mapped = shard_map_nocheck(
+    mapped = jax.shard_map(
         kernel,
         mesh=mesh,
+        check_vma=False,
         in_specs=(
             P(bspec, None, None),  # q replicated over model
             P(bspec, "model", None, None),

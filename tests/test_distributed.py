@@ -69,7 +69,8 @@ batch = {
   'loss_mask': jnp.ones((8, 32), jnp.float32),
 }
 l0, _ = m.loss(params, batch)
-mesh = jax.make_mesh((4, 2), ('data', 'model'))
+mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardingContext.for_arch(cfg, mesh)
 with activate(ctx):
     l1, _ = jax.jit(m.loss)(params, batch)

@@ -181,10 +181,22 @@ def test_engine_with_pallas_decode_kernel(rng):
     rng2 = np.random.default_rng(11)
     prompts = [list(map(int, rng2.integers(1, 400, size=n))) for n in (9, 14)]
     outs = {}
-    for impl in ("ref", "pallas"):
+    for impl in ("ref", "interpret"):
         eng = NeoEngine(cfg, EngineConfig(device_pool_pages=16, host_pool_pages=32,
                                           max_batch_tokens=128, policy="neo"),
                         params=params, kernel_impl=impl)
         rids = [eng.submit(p, 4) for p in prompts]
         outs[impl] = [eng.run_until_done(100)[r] for r in rids]
-    assert outs["pallas"] == outs["ref"]
+    assert outs["interpret"] == outs["ref"]
+
+
+def test_device_decode_impl_follows_platform():
+    """Without an override the platform picks device decode attention:
+    the jnp reference on the CPU (the compiled Pallas kernel on TPU), and
+    never interpret mode."""
+    cfg = get_smoke_config("qwen3-0.6b")
+    eng = NeoEngine(cfg, EngineConfig(device_pool_pages=16, host_pool_pages=32,
+                                      max_batch_tokens=128, policy="neo"))
+    want = "pallas" if jax.default_backend() == "tpu" else "ref"
+    assert (eng.executor.impl, eng.executor.interpret) == (want, False)
+    eng.close()

@@ -132,36 +132,3 @@ def test_prefix_partials_merge_matches_prefix_attention(rng):
     assert ha.prefix_bytes_read > 0  # in-place gather was accounted
     assert ha.busy_time == 0.0  # and kept OUT of the decode-attn EWMA signal
 
-
-def test_io_callback_operands_are_passthrough_numpy():
-    """Guard the io_callback operand pass-through patch (executor import).
-
-    jax 0.4.x round-trips callback operands through an async device_put
-    before invoking the Python callback; on a single-threaded CPU client
-    the only pool thread is parked inside the callback custom-call, so
-    materializing those operands (``int(layer)`` / ``np.asarray(q)``)
-    deadlocks the whole graph.  ``repro.core.executor`` patches the impl
-    to hand the runtime's numpy operands straight through — assert the
-    patch is live and operands arrive already materialized."""
-    import jax
-
-    import repro.core.executor  # noqa: F401  (applies the patch on import)
-    from jax.experimental import io_callback
-
-    if not jax.__version__.startswith("0.4."):
-        pytest.skip("pass-through patch only applies to the jax 0.4.x line")
-
-    seen = {}
-
-    def cb(x):
-        seen["operand_type"] = type(x)
-        return np.asarray(x) * 2.0
-
-    def fn(x):
-        return io_callback(cb, jax.ShapeDtypeStruct((4,), jnp.float32), x,
-                           ordered=True)
-
-    out = jax.jit(fn)(jnp.arange(4, dtype=jnp.float32))
-    np.testing.assert_allclose(np.asarray(out),
-                               np.arange(4, dtype=np.float32) * 2.0)
-    assert seen["operand_type"] is np.ndarray
