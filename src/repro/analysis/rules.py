@@ -4,7 +4,7 @@ Each rule encodes one line of ROADMAP prose as an AST check:
 
 ``tracer-emit-guard``
     Tracing is optional (``attach_tracer``), so every
-    ``tracer.emit/span/instant/counter/...`` call must be dominated by a
+    ``tracer.emit/span/instant/counter/sync/...`` call must be dominated by a
     ``None`` guard — otherwise the first un-traced serve crashes in a
     worker thread where the exception is easy to lose.
 ``no-ordered-callback-in-tp``
@@ -54,8 +54,8 @@ __all__ = [
 
 # every SpanTracer entry point that may be called on a possibly-None tracer
 _EMIT_METHODS = frozenset({
-    "emit", "span", "instant", "counter",
-    "async_begin", "async_end", "async_instant",
+    "emit", "span", "instant", "counter", "sync",
+    "async_begin", "async_end",
 })
 
 
@@ -66,7 +66,7 @@ def _in_dirs(relpath: str, dirs: Sequence[str]) -> bool:
 class TracerEmitGuard(Rule):
     name = "tracer-emit-guard"
     description = (
-        "every tracer emit (emit/span/instant/counter/async_*) must be "
+        "every tracer emit (emit/span/instant/counter/sync/async_*) must be "
         "dominated by a `tracer is not None` guard"
     )
 

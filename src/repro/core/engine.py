@@ -84,6 +84,9 @@ class EngineStats:
     iterations: int = 0
     tokens_out: int = 0
     prefill_tokens: int = 0
+    # slots of the prefill graphs that computed them (batch x bucket, pads
+    # included): 1 - prefill_tokens / prefill_slot_tokens is the pad share
+    prefill_slot_tokens: int = 0
     mode_counts: Dict[str, int] = field(default_factory=dict)
     offloaded_decodes: int = 0
     device_decodes: int = 0
@@ -447,8 +450,6 @@ class NeoEngine:
             req.first_token_time = now
         emitted.append((req.rid, tok))
         self.stats.tokens_out += 1
-        if self.tracer is not None:
-            self.tracer.async_instant(req.rid, "tok", args={"token": tok})
 
     def _finish(self, req: Request, now: float) -> None:
         req.state = RequestState.FINISHED
@@ -647,8 +648,6 @@ class NeoEngine:
         shadow = self._build_shadow(plan)
         if shadow is None:
             self.stats.planahead_skipped += 1
-            if self.tracer is not None:
-                self.tracer.instant("engine", "plan_skip")
             return
         st, shadows, pools_pred, sig_pred = shadow
         sched = self.scheduler
@@ -858,8 +857,10 @@ class NeoEngine:
         it = self.stats.iterations
         if tr is not None:
             # copy handles launched this step stamp their spans with the
-            # iteration id, pairing them with the dispatch window below
+            # iteration id, pairing them with the dispatch window below;
+            # the executor's prefill/decode spans carry it too
             self.transfer.trace_iter = it
+            self.executor.trace_iter = it
 
         # ==== LAUNCH phase ==================================================
         # recompute preemption (both pools full): drop KV, requeue
@@ -1062,14 +1063,15 @@ class NeoEngine:
         futures: List[Tuple[int, Any]] = []
         inline_idx: Optional[int] = None
         if pipelined and lane_rows:
-            def _pre(rws: List[Request]):
+            def _pre(rws: List[Request], li: int):
                 # lane-scoped join: the PCIe swap-outs a lane depends on
                 # complete right before ITS host attention reads the pages
-                return lambda: self.transfer.join_requests(rws, kind="out")
+                return lambda: self.transfer.join_requests(
+                    rws, kind="out", track=f"host{li}")
             thread_lanes = lane_rows if has_dev_lane else lane_rows[:-1]
             for li, rws in enumerate(thread_lanes):
                 futures.append((li, self.executor.submit_host_lane(
-                    rws, pre=_pre(rws), lane=li + 1)))
+                    rws, pre=_pre(rws, li), lane=li + 1)))
             if not has_dev_lane:
                 inline_idx = n_lanes - 1
 
@@ -1079,6 +1081,7 @@ class NeoEngine:
         # prefill emits) — the device is idle there.
         dev_windows: List[Tuple[float, float]] = []
         if plan.prefill:
+            slots0 = self.executor.prefill_slot_tokens
             t0 = time.perf_counter()
             logits = self.executor.prefill(plan.prefill, to_host, self._extras_batch)
             dev_windows.append((t0, time.perf_counter()))
@@ -1092,6 +1095,8 @@ class NeoEngine:
                     tr.async_end(r.rid, "prefill", t=dev_windows[-1][1])
             # computed prefill tokens: prefix-cache hits skip the cached part
             self.stats.prefill_tokens += sum(r.suffix_len for r in plan.prefill)
+            self.stats.prefill_slot_tokens += (
+                self.executor.prefill_slot_tokens - slots0)
             for i, r in enumerate(plan.prefill):
                 if not r.out_tokens:
                     self._emit(r, logits[i], now, emitted)
@@ -1220,11 +1225,7 @@ class NeoEngine:
         # gpu_only swap-outs whose victims do not decode this iteration) so
         # every step ends with pools fully consistent
         if pipelined:
-            d0 = time.perf_counter() if tr is not None else 0.0
-            self.transfer.drain()
-            if tr is not None:
-                tr.emit("engine", "drain", d0, time.perf_counter(),
-                        {"iter": it})
+            self.transfer.drain()  # traced: a swap.join span
             # bytes hidden under compute: copy-window overlap with this
             # step's dispatch window (page-table building + prefill + both
             # decode lanes)
@@ -1392,6 +1393,7 @@ class NeoEngine:
                 extras = {k: jnp.asarray(v)[None] for k, v in extras.items()}
             logits = self.executor.prefill(r, slot, extras)
             self.stats.prefill_tokens += r.prompt_len
+            self.stats.prefill_slot_tokens += r.prefill_len  # B=1, no pad
             self._emit(r, logits, now, emitted)
         rows = [r for r in plan.decode_rows if r.state == RequestState.RUNNING
                 and r not in plan.prefill]
@@ -1420,6 +1422,8 @@ class NeoEngine:
         try:
             if self.transfer is not None:
                 self.transfer.close()
+                # the closing drain's joins, as a step's mirror would count
+                self.stats.swap_wait_time = self.transfer.stats.wait_time
         finally:
             if self.paged:
                 self.executor.close()

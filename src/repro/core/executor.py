@@ -77,6 +77,11 @@ def _bucket(n: int, minimum: int = 8) -> int:
     return b
 
 
+def _suffix_bucket(reqs: List[Request]) -> int:
+    """Token bucket S of a cached-prefill graph over ``reqs``' suffixes."""
+    return _bucket(max(r.suffix_len for r in reqs), 16)
+
+
 class PagedExecutor:
     """Paged decode + bucketed prefill for decoder-only attention families."""
 
@@ -160,8 +165,13 @@ class PagedExecutor:
         # their separate per-lane state dicts)
         self._cb_prefix_state: Dict[str, np.ndarray] = {}
         # tracing (repro.obs): set by the engine when EngineConfig.tracing
-        # is on; host-attention callbacks and lane threads emit spans
+        # is on; host-attention callbacks and lane threads emit spans, and
+        # prefill/decode steps on the engine thread carry ``trace_iter``
         self.tracer = None
+        self.trace_iter = 0
+        # padded tokens of every prefill graph run (B x S), beside the
+        # engine's computed ``prefill_tokens``; the engine mirrors it
+        self.prefill_slot_tokens = 0
 
     # ------------------------------------------------------------------
     # host attention callback (one per layer, ordered)
@@ -425,12 +435,19 @@ class PagedExecutor:
                     self.params_tp, tokens, positions, dev_bt, dev_lens,
                     is_host, page_ids, offsets, dev.k, dev.v,
                 )
-            return np.asarray(logits[:n])
-        logits, dev.k, dev.v = fn(
-            self.params, tokens, positions, dev_bt, dev_lens, is_host,
-            page_ids, offsets, dev.k, dev.v,
-        )
-        return np.asarray(logits[:n])
+        else:
+            logits, dev.k, dev.v = fn(
+                self.params, tokens, positions, dev_bt, dev_lens, is_host,
+                page_ids, offsets, dev.k, dev.v,
+            )
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
+        out = np.asarray(logits[:n])
+        if tr is not None:
+            # the full-vocab fetch; it waits for the graph to finish
+            tr.emit("decode", "decode.logits", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "rows": n, "bytes": out.nbytes})
+        return out
 
     # batch-0 is the fused graph over device + cpu0 rows — exactly the serial
     # entry restricted to its sub-batch.
@@ -569,26 +586,17 @@ class PagedExecutor:
         ``(logits [n,V], (start, end))`` perf_counter stamps.
 
         ``pre`` runs on the lane thread before any page is read — the
-        engine passes the lane-scoped swap-out join there, so PCIe
-        transfers complete exactly when (and only when) the dependent host
-        attention needs them.
+        engine passes the lane-scoped swap-out join there (traced on this
+        lane's ``host<lane-1>`` track), so PCIe transfers complete exactly
+        when (and only when) the dependent host attention needs them.
         """
 
         def run_lane() -> Tuple[np.ndarray, Tuple[float, float]]:
-            tr = self.tracer
-            track = f"host{lane - 1}"  # engine lane index li = lane - 1
             t0 = time.perf_counter()
             if pre is not None:
-                j0 = time.perf_counter() if tr is not None else 0.0
-                pre()
-                if tr is not None:
-                    tr.emit(track, "join_out", j0, time.perf_counter())
-            c0 = time.perf_counter() if tr is not None else 0.0
+                pre()  # traced: a swap.join span on this lane's host track
             out = self.decode_host_lane(rows, window, lane=lane)
-            end = time.perf_counter()
-            if tr is not None:
-                tr.emit(track, "compute", c0, end, {"rows": len(rows)})
-            return out, (t0, end)
+            return out, (t0, time.perf_counter())
 
         return self._lane_pool.submit(run_lane)
 
@@ -666,10 +674,16 @@ class PagedExecutor:
 
     def _prefill_cold(self, reqs: List[Request], to_host: List[bool],
                       extras_fn=None) -> np.ndarray:
+        """Traced, on the ``prefill`` track: ``prefill.graph`` (inputs and
+        dispatch), one ``prefill.place`` per request (pad, reshape and the
+        pool write or the host copy) and ``prefill.logits`` (the fetch)."""
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
         n = len(reqs)
         S = _bucket(max(r.prefill_len for r in reqs), 16)
         B = n
         page = self.page
+        self.prefill_slot_tokens += B * S
         tokens = np.zeros((B, S), np.int32)
         lens = np.zeros((B,), np.int32)
         for i, r in enumerate(reqs):
@@ -687,9 +701,12 @@ class PagedExecutor:
             logits, k_all, v_all = self.prefill_fn(B, S)(
                 self.params, tokens, lens, extras
             )
+        if tr is not None:
+            tr.emit("prefill", "prefill.graph", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "B": B, "S": S})
         # scatter into pools, page-granular (device) / numpy (host)
-        k_np: Optional[np.ndarray] = None
         for i, (r, host) in enumerate(zip(reqs, to_host)):
+            p0 = time.perf_counter() if tr is not None else 0.0
             npages = len(r.pages)
             S_pad = npages * page
             kr = k_all[:, i]
@@ -710,7 +727,22 @@ class PagedExecutor:
                 self.pool.add_swap_bytes(k_host.nbytes + v_host.nbytes)
             else:
                 self.pool.device.put_pages(r.pages, kr, vr)
-        return np.asarray(logits)
+            if tr is not None:
+                tr.emit("prefill", "prefill.place", p0, time.perf_counter(),
+                        {"iter": self.trace_iter, "rid": r.rid,
+                         "pages": npages, "to_host": bool(host),
+                         "bytes": kr.nbytes + vr.nbytes})
+        return self._prefill_logits(logits, "prefill")
+
+    def _prefill_logits(self, logits, track: str) -> np.ndarray:
+        """The first-token logits fetch that ends every prefill."""
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
+        out = np.asarray(logits)
+        if tr is not None:
+            tr.emit(track, "prefill.logits", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "bytes": out.nbytes})
+        return out
 
     # ------------------------------------------------------------------
     # partial prefill over a cached prefix (prefix cache)
@@ -755,6 +787,10 @@ class PagedExecutor:
         """
         host_idx = [i for i, h in enumerate(to_host) if h]
         gpu_idx = [i for i, h in enumerate(to_host) if not h]
+        for leg in (host_idx, gpu_idx):  # each leg's graph: B x S slots
+            if leg:
+                self.prefill_slot_tokens += len(leg) * _suffix_bucket(
+                    [reqs[i] for i in leg])
         if host_idx and gpu_idx:
             # the two legs touch disjoint rows and pools: run the CPU-heavy
             # host-partials leg on a lane thread so it overlaps the device
@@ -762,7 +798,8 @@ class PagedExecutor:
             # concurrency contract as decode_host_lane — the host-prefix
             # graph never touches the device KV pool)
             fut = self._lane_pool.submit(
-                self._prefill_cached_host, [reqs[i] for i in host_idx])
+                self._prefill_cached_host, [reqs[i] for i in host_idx],
+                "prefill-lane")
             out_g = self._prefill_cached_gather([reqs[i] for i in gpu_idx])
             out_h = fut.result()
             out = np.zeros((len(reqs), out_h.shape[-1]), np.float32)
@@ -774,31 +811,43 @@ class PagedExecutor:
         return self._prefill_cached_gather(reqs)
 
     def _scatter_suffix(self, reqs: List[Request], suffix_lens: np.ndarray,
-                        k_all, v_all, to_host: bool) -> None:
+                        k_all, v_all, to_host: bool, track: str) -> None:
         """Token-granular suffix-KV scatter: the suffix starts at offset
-        ``cached_len``, which may sit mid-page (inside the COW page)."""
+        ``cached_len``, which may sit mid-page (inside the COW page).
+        Traced: one ``prefill.place`` span per request on ``track``."""
         page, cfg = self.page, self.cfg
         L = self.pool.host.num_layers
         KV, hd = cfg.num_kv_heads, cfg.head_dim
         pool = self.pool.host if to_host else self.pool.device
+        tr = self.tracer
         for i, r in enumerate(reqs):
+            p0 = time.perf_counter() if tr is not None else 0.0
             suf = int(suffix_lens[i])
             pos = r.cached_len + np.arange(suf)
             pids = np.asarray([r.pages[p // page] for p in pos], np.int32)
             offs = (pos % page).astype(np.int32)
             pool.write_token_range(pids, offs, k_all[:, i, :suf], v_all[:, i, :suf])
+            nb = 2 * suf * L * KV * hd * pool.k.dtype.itemsize
             if to_host:  # layer-wise PCIe swap of the freshly computed KV
-                nb = 2 * suf * L * KV * hd * self.pool.host.k.dtype.itemsize
                 self.pool.add_swap_bytes(nb)
+            if tr is not None:
+                tr.emit(track, "prefill.place", p0, time.perf_counter(),
+                        {"iter": self.trace_iter, "rid": r.rid,
+                         "pages": len(set(pids.tolist())),
+                         "to_host": bool(to_host), "bytes": nb})
 
     def _prefill_cached_gather(self, reqs: List[Request]) -> np.ndarray:
         """Device rows: gather the cached prefix into the prefix-attention
-        graph input, then scatter the suffix KV into the device pool."""
+        graph input, then scatter the suffix KV into the device pool.
+        Traced like the cold path (the prefix gather is part of
+        ``prefill.graph``)."""
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
         cfg, page = self.cfg, self.page
         n = len(reqs)
         L = self.pool.device.num_layers
         KV, hd = cfg.num_kv_heads, cfg.head_dim
-        S = _bucket(max(r.suffix_len for r in reqs), 16)
+        S = _suffix_bucket(reqs)
         t_pages = _bucket(max(-(-r.cached_len // page) for r in reqs), 1)
         T = t_pages * page
 
@@ -826,8 +875,12 @@ class PagedExecutor:
             # into the mesh-sharded device pool
             k_all = np.asarray(k_all)
             v_all = np.asarray(v_all)
-        self._scatter_suffix(reqs, suffix_lens, k_all, v_all, to_host=False)
-        return np.asarray(logits)
+        if tr is not None:
+            tr.emit("prefill", "prefill.graph", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "B": n, "S": S, "T": T})
+        self._scatter_suffix(reqs, suffix_lens, k_all, v_all, to_host=False,
+                             track="prefill")
+        return self._prefill_logits(logits, "prefill")
 
     # -- zero-copy host-prefix path ------------------------------------------
     def _host_prefix_cb(self, layer, q):
@@ -902,14 +955,19 @@ class PagedExecutor:
             self._prefill_fns[key] = build(B, S)
         return self._prefill_fns[key]
 
-    def _prefill_cached_host(self, reqs: List[Request]) -> np.ndarray:
+    def _prefill_cached_host(self, reqs: List[Request],
+                             track: str = "prefill") -> np.ndarray:
         """Host rows: ZERO-COPY host serving.  The cached prefix pages stay
         in the host pool and are read in place, at their absolute positions,
         by the per-layer prefix-partials callback; only the computed suffix
-        KV crosses PCIe (the writeback into the host pool)."""
+        KV crosses PCIe (the writeback into the host pool).  Traced on
+        ``track`` (the calling thread's): ``prefill.graph`` also covers the
+        graph's drain and the suffix-KV fetch."""
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
         page = self.page
         n = len(reqs)
-        S = _bucket(max(r.suffix_len for r in reqs), 16)
+        S = _suffix_bucket(reqs)
         max_pp = max(-(-r.cached_len // page) for r in reqs)
         tokens = np.zeros((n, S), np.int32)
         suffix_lens = np.zeros((n,), np.int32)
@@ -945,8 +1003,12 @@ class PagedExecutor:
         jax.block_until_ready((logits, k_all, v_all))
         k_all = np.asarray(k_all)
         v_all = np.asarray(v_all)
-        self._scatter_suffix(reqs, suffix_lens, k_all, v_all, to_host=True)
-        return np.asarray(logits)
+        if tr is not None:
+            tr.emit(track, "prefill.graph", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "B": n, "S": S})
+        self._scatter_suffix(reqs, suffix_lens, k_all, v_all, to_host=True,
+                             track=track)
+        return self._prefill_logits(logits, track)
 
 
 # ---------------------------------------------------------------------------

@@ -285,6 +285,8 @@ class TransferEngine:
             h._event.set()
             return h
         idx = np.asarray(req.pages, np.int32)
+        tr = self.tracer
+        t0 = time.perf_counter() if tr is not None else 0.0
         # Snapshot the device pages to a host staging buffer NOW (the jax
         # gather against the current immutable pool buffers; materialized
         # here so the worker never queues work on the device — on this
@@ -294,6 +296,11 @@ class TransferEngine:
         host_dtype = host.k.dtype
         k_np = np.asarray(dev.k[:, idx], host_dtype)
         v_np = np.asarray(dev.v[:, idx], host_dtype)
+        if tr is not None:
+            tr.emit("swap", "swap.stage", t0, time.perf_counter(),
+                    {"iter": self.trace_iter, "rid": req.rid,
+                     "pages": len(req.pages),
+                     "bytes": k_np.nbytes + v_np.nbytes})
         new_pages = host.alloc(len(req.pages))
         dev.free(req.pages)
         req.pages = new_pages
@@ -453,13 +460,17 @@ class TransferEngine:
     # ------------------------------------------------------------------
     # join
     # ------------------------------------------------------------------
-    def join(self, handles: Iterable[TransferHandle]) -> None:
+    def join(self, handles: Iterable[TransferHandle], *,
+             track: str = "swap") -> None:
         """Block until the given transfers are complete and safe to use.
 
         Swap-in handles apply their staged device write here — only call
         join() on swap-ins from the engine thread.  Time spent blocked is
-        accounted in ``stats.wait_time``.
+        accounted in ``stats.wait_time``; traced, the same interval is a
+        ``swap.join`` span on ``track`` (the calling thread's own track),
+        with each staged write inside it a ``swap.apply`` span.
         """
+        tr = self.tracer
         t0 = time.perf_counter()
         try:
             for h in handles:
@@ -471,14 +482,23 @@ class TransferEngine:
                 if h.error is not None:
                     raise h.error
                 if apply is not None:
+                    a0 = time.perf_counter() if tr is not None else 0.0
                     apply()
+                    if tr is not None:
+                        tr.emit(track, "swap.apply", a0, time.perf_counter(),
+                                {"iter": h.trace_iter, "rid": h.req.rid,
+                                 "bytes": h.nbytes})
         finally:
+            t1 = time.perf_counter()
             with self._lock:
-                self.stats.wait_time += time.perf_counter() - t0
+                self.stats.wait_time += t1 - t0
                 self._pending = [p for p in self._pending if not p._joined]
+            if tr is not None:
+                tr.emit(track, "swap.join", t0, t1, {"iter": self.trace_iter})
 
     def join_requests(self, reqs: Iterable[Request],
-                      kind: Optional[str] = None) -> None:
+                      kind: Optional[str] = None, *,
+                      track: str = "swap") -> None:
         """Lane-scoped join point: block until every pending transfer whose
         request is in ``reqs`` (optionally restricted to ``kind`` "out" /
         "in") is complete.
@@ -487,13 +507,14 @@ class TransferEngine:
         host attention reads the lane's pages — swap-outs join against the
         lane that consumes them rather than one global barrier.  Only call
         with ``kind="in"`` (or ``kind=None`` over swap-ins) from the engine
-        thread: swap-in joins apply a staged device write.
+        thread: swap-in joins apply a staged device write.  ``track`` names
+        the calling thread's trace track (see :meth:`join`).
         """
         rids = {r.rid for r in reqs}
         with self._lock:
             hs = [h for h in self._pending
                   if h.req.rid in rids and (kind is None or h.kind == kind)]
-        self.join(hs)
+        self.join(hs, track=track)
 
     def drain(self) -> None:
         """Join every outstanding transfer (step barrier / shutdown)."""

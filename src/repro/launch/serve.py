@@ -54,6 +54,7 @@ def _mirror_stats(engine: NeoEngine, metrics: ServeMetrics) -> None:
     metrics.lane_count_steps = dict(engine.stats.lane_counts)
     metrics.lane_busy = dict(engine.stats.lane_busy_time)
     metrics.prefill_tokens_computed = engine.stats.prefill_tokens
+    metrics.prefill_slot_tokens = engine.stats.prefill_slot_tokens
     metrics.planahead_hits = engine.stats.planahead_hits
     metrics.planahead_replans = engine.stats.planahead_replans
     metrics.planahead_skipped = engine.stats.planahead_skipped

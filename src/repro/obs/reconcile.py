@@ -2,8 +2,8 @@
 
 :func:`reconcile` recomputes the engine's overlap accounting **from the
 spans alone** — per-lane busy time, realized/ideal pipeline overlap,
-bubble fraction, swap bytes hidden under compute, and plan-ahead hidden
-time — using the exact same formulas ``NeoEngine._step_paged`` applies
+bubble fraction, swap bytes hidden under compute, swap join wait, and
+plan-ahead hidden time — using the exact same formulas ``NeoEngine._step_paged`` applies
 to its live windows, then asserts agreement with the counters the engine
 accumulated.  A divergence means either the instrumentation or the
 accounting drifted: the trace is a standing audit of the numbers every
@@ -25,6 +25,10 @@ instrumentation; all timestamps are shared-clock ``perf_counter``):
   previous iteration's lanes).
 * ``copy-out`` / ``copy-in`` / ``copy-all`` tracks — one span per
   async copy job with ``args.nbytes`` and ``args.iter``.
+* ``swap.join`` spans on any track (``swap`` for the engine thread,
+  ``host<k>`` for a lane's swap-out join) — the intervals
+  ``TransferEngine.join`` blocked, which ``EngineStats.swap_wait_time``
+  sums.
 
 The ``spec`` track (batched draft-verification passes) is deliberately
 OUTSIDE this audit, like ``copy-sync``: verify wall time accrues only to
@@ -128,6 +132,7 @@ def reconcile(tracer: SpanTracer, stats, *, rtol: float = 1e-6,
     plan_busy = 0.0
     hideable_plan = 0.0
     adopt_durs: List[float] = []
+    swap_wait = 0.0
 
     for e in events:
         if e.ph == "X" and e.track == "device":
@@ -140,6 +145,8 @@ def reconcile(tracer: SpanTracer, stats, *, rtol: float = 1e-6,
             it = (e.args or {}).get("iter")
             if it is not None:
                 lanes_by_iter.setdefault(it, []).append(e)
+        elif e.ph == "X" and e.name == "swap.join":
+            swap_wait += e.t1 - e.t0
         elif e.ph == "X" and e.track == "engine" and e.name == "dispatch":
             dispatch_by_iter[(e.args or {})["iter"]] = (e.t0, e.t1)
         elif e.ph == "X" and e.track.startswith(_COPY_TRACKS):
@@ -205,6 +212,9 @@ def reconcile(tracer: SpanTracer, stats, *, rtol: float = 1e-6,
             _close(stats.planahead_hidden_time, hidden, rtol, atol))
     rep.add("plan_busy_time", stats.plan_busy_time, plan_busy,
             _close(stats.plan_busy_time, plan_busy, rtol, atol))
+
+    rep.add("swap_wait_time", stats.swap_wait_time, swap_wait,
+            _close(stats.swap_wait_time, swap_wait, rtol, atol))
 
     # ---- swap bytes hidden under the dispatch window (exact) ---------
     hidden_bytes = 0

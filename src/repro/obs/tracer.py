@@ -23,14 +23,23 @@ Event model (one namedtuple per ring slot):
 * ``ph="X"`` — complete span ``[t0, t1]`` on ``track``.
 * ``ph="i"`` — instant on ``track``.
 * ``ph="C"`` — counter sample (``args`` = {series: value}).
-* ``ph="b"/"e"/"n"`` — async begin/end/instant keyed by ``rid`` (request
-  lifecycle spans; rendered as one async row per request id).
+* ``ph="b"/"e"`` — async begin/end keyed by ``rid`` (request lifecycle
+  spans; rendered as one async row per request id).
+
+Clock anchor: :meth:`SpanTracer.sync` enters a ``jax.profiler.
+TraceAnnotation`` and records a ``sync`` instant holding the perf_counter
+readings taken just before entering and just after leaving it.  A profiler
+trace that holds two or more such annotations can place every span of this
+tracer on its own clock (``bench/program_trace.py`` fits the line), so no
+span needs to be mirrored as an annotation.
 
 Export:
 
 * :meth:`SpanTracer.export_chrome` — Chrome trace-event JSON.  Loadable
   in Perfetto / ``chrome://tracing``: one named thread row per track,
-  counter tracks, and request lifecycles as async events.
+  counter tracks, and request lifecycles as async events.  Timestamps lie
+  on an integer-nanosecond grid relative to the trace's first stamp, so
+  spans that share a perf_counter stamp share an exported end exactly.
 * :meth:`SpanTracer.export_counters_jsonl` — the counter time-series as
   one JSON object per line (a cheap sink for dashboards / pandas).
 """
@@ -48,6 +57,22 @@ SpanEvent = namedtuple("SpanEvent", ["ph", "track", "name", "t0", "t1", "rid", "
 
 # preferred Perfetto row order (everything else: first-seen order after these)
 _TRACK_ORDER = ("engine", "planner", "sched", "device")
+
+# the profiler annotation that SpanTracer.sync enters (a trace reader pairs
+# each one with the tracer's ``sync`` instant)
+SYNC_NAME = "obs.sync"
+
+# tracks that only the thread driving the engine writes; the "sched" track
+# also carries the planner thread's speculative plans (``speculative`` arg)
+ENGINE_TRACKS = ("engine", "device", "sched", "prefill", "decode", "swap",
+                 "prefix", "spec")
+
+
+def on_engine_thread(track: str, args: Optional[Dict[str, Any]]) -> bool:
+    """Whether a span on ``track`` with ``args`` was emitted by the thread
+    that drives the engine (the one whose waits leave the device idle)."""
+    return track in ENGINE_TRACKS and not (
+        track == "sched" and (args or {}).get("speculative"))
 
 
 class SpanTracer:
@@ -97,6 +122,21 @@ class SpanTracer:
         t = time.perf_counter() if t is None else t
         self._push(SpanEvent("C", "counters", name, t, t, None, dict(values)))
 
+    def sync(self) -> None:
+        """Clock anchor: enter and leave a ``jax.profiler.TraceAnnotation``
+        called :data:`SYNC_NAME` and record a ``sync`` instant (``engine``
+        track: the thread that drives the engine calls it) whose ``t0``/
+        ``t1`` are the perf_counter readings taken just before entering and
+        just after leaving it."""
+        import jax  # lazily: the tracer itself needs no JAX
+
+        ann = jax.profiler.TraceAnnotation(SYNC_NAME)
+        t0 = time.perf_counter()
+        ann.__enter__()
+        ann.__exit__(None, None, None)
+        t1 = time.perf_counter()
+        self._push(SpanEvent("i", "engine", "sync", t0, t1, None, {"name": SYNC_NAME}))
+
     # -- request lifecycle (async events keyed by rid) -------------------
     def async_begin(self, rid: int, name: str, t: Optional[float] = None,
                     args: Optional[Dict[str, Any]] = None) -> None:
@@ -107,11 +147,6 @@ class SpanTracer:
                   args: Optional[Dict[str, Any]] = None) -> None:
         t = time.perf_counter() if t is None else t
         self._push(SpanEvent("e", "request", name, t, t, rid, args))
-
-    def async_instant(self, rid: int, name: str, t: Optional[float] = None,
-                      args: Optional[Dict[str, Any]] = None) -> None:
-        t = time.perf_counter() if t is None else t
-        self._push(SpanEvent("n", "request", name, t, t, rid, args))
 
     # ------------------------------------------------------------------
     # inspection
@@ -152,10 +187,18 @@ class SpanTracer:
 
         One named thread row per track (pid 1, "neo-engine"), counter
         tracks from :meth:`counter` samples, and request lifecycle spans
-        as async ("b"/"e"/"n") events grouped by request id.  Timestamps
-        are perf_counter seconds scaled to microseconds.
+        as async ("b"/"e") events grouped by request id.  Each stamp is
+        rounded once to whole nanoseconds after the trace's first stamp
+        (``otherData.t0_perf_counter_s``) and written in microseconds;
+        ``dur`` is the difference of two rounded stamps, so a child span
+        that shares its parent's end stamp ends exactly where it does.
         """
         events = self.events()
+        base = min((e.t0 for e in events), default=0.0)
+
+        def ns(t: float) -> int:
+            return round((t - base) * 1e9)
+
         tids = {t: i + 1 for i, t in enumerate(self.tracks())}
         out: List[Dict[str, Any]] = [
             {"ph": "M", "pid": 1, "tid": 0, "name": "process_name",
@@ -168,10 +211,11 @@ class SpanTracer:
                         "name": "thread_sort_index",
                         "args": {"sort_index": tid}})
         for e in events:
-            ts = e.t0 * 1e6
+            n0 = ns(e.t0)
+            ts = n0 / 1e3
             if e.ph == "X":
                 ev = {"ph": "X", "pid": 1, "tid": tids[e.track], "name": e.name,
-                      "cat": e.track, "ts": ts, "dur": (e.t1 - e.t0) * 1e6}
+                      "cat": e.track, "ts": ts, "dur": (ns(e.t1) - n0) / 1e3}
             elif e.ph == "i":
                 ev = {"ph": "i", "pid": 1, "tid": tids[e.track], "name": e.name,
                       "cat": e.track, "ts": ts, "s": "t"}
@@ -191,6 +235,7 @@ class SpanTracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "tracer": "repro.obs",
+                "t0_perf_counter_s": base,
                 "events_recorded": self.total,
                 "events_dropped": self.dropped,
             },

@@ -46,8 +46,11 @@ def validate(path: str, *, expect_host_lane: bool = False,
             fails.append(f"malformed span {e['name']!r}")
         track = tid_names[e["tid"]]
         spans_per_track[track] = spans_per_track.get(track, 0) + 1
+        # back onto the exporter's integer-nanosecond grid: ends compare
+        # exactly, so spans that share a stamp nest
+        t0 = round(e["ts"] * 1e3)
         track_spans.setdefault(track, []).append(
-            (e["ts"], e["ts"] + e.get("dur", 0), e["name"]))
+            (t0, t0 + round(e.get("dur", 0) * 1e3), e["name"]))
 
     # Single-writer well-formedness: within any one track the spans must
     # nest or be disjoint.  Two overlapping-but-not-nested spans mean two
@@ -61,7 +64,8 @@ def validate(path: str, *, expect_host_lane: bool = False,
             (a0, a1, an), (b0, b1, bn) = bad
             fails.append(
                 f"track {track!r} is not single-writer: span {an!r} "
-                f"[{a0},{a1}] overlaps {bn!r} [{b0},{b1}] without nesting")
+                f"[{a0},{a1}] ns overlaps {bn!r} [{b0},{b1}] ns without "
+                "nesting")
 
     # every named lane-style track must actually carry spans
     for tid, track in tid_names.items():
@@ -105,8 +109,9 @@ def validate(path: str, *, expect_host_lane: bool = False,
 
 def _overlap_violation(spans):
     """First pair of spans in one track that overlap without nesting, or
-    None.  Spans are (t0, t1, name); sorted enclosing-first, a stack proves
-    nest-or-disjoint exactly like the tracer's own design contract."""
+    None.  Spans are (t0, t1, name) in integer nanoseconds; sorted
+    enclosing-first, a stack proves nest-or-disjoint exactly like the
+    tracer's own design contract."""
     stack = []
     for t0, t1, name in sorted(spans, key=lambda s: (s[0], -s[1])):
         while stack and stack[-1][1] <= t0:

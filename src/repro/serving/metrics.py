@@ -102,6 +102,9 @@ class ServeMetrics:
     lane_busy: Dict[str, float] = field(default_factory=dict)
     # prefix cache (PrefixCacheStats mirror; zeros when the cache is off)
     prefill_tokens_computed: int = 0
+    # prefill graph slots (batch x bucket, EngineStats mirror): the pad share
+    # is 1 - prefill_tokens_computed / prefill_slot_tokens
+    prefill_slot_tokens: int = 0
     prefix_hit_rate: float = 0.0
     prefix_hits: int = 0
     prefix_lookups: int = 0
@@ -293,6 +296,7 @@ class ServeMetrics:
             "lane_busy_s": {k: round(v, 3) for k, v in sorted(self.lane_busy.items())},
             # two-tier prefix cache (all zeros when disabled)
             "prefill_tokens_computed": self.prefill_tokens_computed,
+            "prefill_slot_tokens": self.prefill_slot_tokens,
             "hit_rate": round(self.prefix_hit_rate, 3),
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "prefix_hits": self.prefix_hits,

@@ -77,6 +77,16 @@ def test_ring_no_overflow_keeps_order():
     assert [e.name for e in tr.events()] == [f"s{i}" for i in range(5)]
 
 
+def test_sync_records_the_clock_anchor():
+    """sync() brackets a profiler annotation with two perf_counter readings
+    (no trace needs to be running)."""
+    tr = SpanTracer()
+    tr.sync()
+    (e,) = tr.events()
+    assert (e.ph, e.track, e.name, e.args) == ("i", "engine", "sync", {"name": "obs.sync"})
+    assert 0 <= e.t1 - e.t0 < 0.5
+
+
 def test_reconcile_refuses_wrapped_ring():
     tr = SpanTracer(capacity=2)
     for i in range(5):
@@ -112,9 +122,13 @@ def test_export_chrome_shape(tmp_path):
 
     spans = [e for e in evs if e["ph"] == "X"]
     assert all("ts" in e and "dur" in e for e in spans)
+    # microseconds after the trace's first stamp, on a whole-ns grid
+    assert doc["otherData"]["t0_perf_counter_s"] == 1.0
     step = next(e for e in spans if e["name"] == "step")
-    assert step["ts"] == pytest.approx(1.0 * 1e6)
-    assert step["dur"] == pytest.approx(1.0 * 1e6)
+    assert step["ts"] == 0.0
+    assert step["dur"] == 1.0 * 1e6
+    lane = next(e for e in spans if e["name"] == "lane")
+    assert lane["ts"] == pytest.approx(0.2 * 1e6) and lane["dur"] == pytest.approx(0.6 * 1e6)
 
     counters = [e for e in evs if e["ph"] == "C"]
     assert counters and counters[0]["args"] == {"waiting": 3, "running": 2}
@@ -149,17 +163,46 @@ def test_tracing_bitwise_identity(policy, kw, setup, rng):
     cfg, params = setup
     prompts = _prompts(rng, (7, 19, 26, 12))
     outs = {}
+    slots = {}
     for tracing in (False, True):
         eng = _make(cfg, params, tracing=tracing, policy=policy, **kw)
         rids = [eng.submit(p, 8) for p in prompts]
         done = eng.run_until_done(300)
         outs[tracing] = [done[r] for r in rids]
+        st = eng.stats
+        slots[tracing] = (st.prefill_tokens, st.prefill_slot_tokens)
+        assert 0 < st.prefill_tokens <= st.prefill_slot_tokens
         if tracing:
             assert eng.tracer is not None and eng.tracer.total > 0
+            _assert_engine_spans(eng.tracer, prompts)
         else:
             assert eng.tracer is None
         eng.close()
     assert outs[True] == outs[False], f"{policy}: tracing changed outputs"
+    assert slots[True] == slots[False]
+
+
+def _assert_engine_spans(tracer, prompts):
+    """The spans inside the engine: prefill's steps on the ``prefill`` track
+    (one ``prefill.place`` per request, with its rid), the decode logits
+    fetch, and a ``swap.join`` per join; every one carries ``iter``."""
+    spans = [e for e in tracer.events() if e.ph == "X"]
+    by_name = {}
+    for e in spans:
+        by_name.setdefault(e.name, []).append(e)
+    for name in ("prefill.graph", "prefill.place", "prefill.logits"):
+        assert by_name[name] and {e.track for e in by_name[name]} == {"prefill"}
+    place = by_name["prefill.place"]
+    assert {e.args["rid"] for e in place} >= set(range(len(prompts)))
+    assert all(e.args["pages"] > 0 and e.args["bytes"] > 0 and "to_host" in e.args
+               for e in place)
+    assert {e.track for e in by_name.get("decode.logits", [])} <= {"decode"}
+    assert by_name["swap.join"]
+    for e in spans:
+        if e.name.startswith(("prefill.", "decode.", "swap.")):
+            assert "iter" in e.args, e
+    names = {e.name for e in tracer.events()}
+    assert not names & {"tok", "plan_skip", "drain", "join_out"}
 
 
 def test_tracing_bitwise_identity_prefix_cache(setup, rng):
@@ -263,6 +306,8 @@ def test_reconcile_mixed_neo_tight_pool(setup, rng):
     rep = _reconcile_run(cfg, params, rng, policy="neo", planahead=True)
     assert "swap_hidden_bytes" in rep.checks
     assert "bubble_fraction" in rep.checks
+    # swap.join spans (engine thread and lane threads) sum to the join wait
+    assert rep.checks["swap_wait_time"]["stats"] > 0
 
 
 def test_reconcile_planahead_adoptions(setup, rng):
@@ -429,6 +474,29 @@ def test_validate_flags_overlap_on_unsharded_track_too(tmp_path):
     doc = _chrome_doc({"copy-out": [(0, 5, "out"), (4, 4, "out")]})
     fails = _validate_doc(tmp_path, doc)
     assert any("copy-out" in f and "single-writer" in f for f in fails)
+
+
+def test_export_nests_shared_end_stamps(tmp_path):
+    """Spans that share a perf_counter stamp nest in the export: thousands of
+    lane/compute pairs sharing their end at perf_counter magnitudes (1e5 s),
+    where a float microsecond ``ts + dur`` rounds one ulp apart; a child that
+    ends one nanosecond past its parent is still flagged."""
+    gen = np.random.default_rng(13)
+    tr = SpanTracer(capacity=1 << 14)
+    t = 1.0e5
+    for _ in range(4000):
+        t0 = t + gen.uniform(0.0, 1e-3)
+        t1 = t0 + gen.uniform(1e-6, 1e-2)
+        tr.emit("host0", "lane", t0, t1)
+        tr.emit("host0", "compute", t0 + gen.uniform(0.0, t1 - t0), t1)
+        t = t1
+    tr.emit("host1", "lane", t, t + 1e-3)
+    tr.emit("host1", "compute", t + 5e-4, t + 1e-3 + 1e-9)
+    path = tmp_path / "trace.json"
+    tr.export_chrome(str(path))
+    from repro.obs.validate import validate
+    bad = [f for f in validate(str(path)) if "single-writer" in f]
+    assert len(bad) == 1 and "'host1'" in bad[0], bad
 
 
 def test_validate_real_tp2_export_passes(tmp_path):

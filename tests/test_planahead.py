@@ -175,6 +175,8 @@ def test_run_online_streams_and_finishes(setup, rng):
     assert len(m.finished) == 6
     assert streamed == finals
     assert m.planahead_hits > 0
+    s = m.summary()  # prefill's computed tokens and the graph slots they took
+    assert 0 < s["prefill_tokens_computed"] <= s["prefill_slot_tokens"]
     for rec in m.finished:
         assert rec.ttft is not None and rec.ttft >= 0
         assert rec.tpot is None or rec.tpot > 0
