@@ -719,9 +719,7 @@ class PagedExecutor:
             kr = kr.reshape(kr.shape[0], npages, page, *kr.shape[2:])
             vr = vr.reshape(vr.shape[0], npages, page, *vr.shape[2:])
             if host:
-                host_dt = self.pool.host.k.dtype
-                k_host = np.asarray(kr, host_dt)
-                v_host = np.asarray(vr, host_dt)
+                k_host, v_host = np.asarray(kr), np.asarray(vr)
                 self.pool.host.put_pages(r.pages, k_host, v_host)
                 # layer-wise PCIe swap of the freshly computed KV
                 self.pool.add_swap_bytes(k_host.nbytes + v_host.nbytes)

@@ -5,12 +5,19 @@ The paper's kernel properties we preserve:
 
 * **paged KV** (vLLM-style block tables) to avoid fragmentation;
 * **flash-decoding split** (Dao et al.): the KV sequence of each request is
-  partitioned into page-granular tasks that touch contiguous memory; tasks are
-  dispatched over worker threads and partial softmax results are merged with
-  the standard (m, l, acc) log-sum-exp combine;
-* **bandwidth-first layout**: pages are gathered with one contiguous fancy
-  index per request (the numpy analogue of the SIMD streaming loads);
-* **GQA aware**: scores are computed per KV head over its query group.
+  partitioned into page-granular blocks; every (row, block) pair is one task
+  on the worker threads, and each row's partial softmax results are merged
+  with the standard (m, l, acc) log-sum-exp combine in a fixed block order;
+* **bandwidth-first layout**: the host pool keeps the device's 16-bit bits,
+  a task gathers its pages with one ``np.take`` into per-thread scratch and
+  widens them to float32 there, so DRAM streams 2 bytes per element once;
+* **GQA aware**: scores and outputs are per-KV-head batched ``np.matmul``
+  (BLAS) over the head's query group.
+
+Every step of a task (gather, integer widen, BLAS, ``exp``) is a numpy loop
+that releases the GIL, so tasks overlap on the worker threads. A row's
+output depends only on its own blocks, so it is bitwise the same for any
+thread count and any other rows in the call.
 
 On a real TPU VM this module runs on the host cores next to the accelerator
 (the engine calls it through an ordered ``io_callback`` from inside the jitted
@@ -24,9 +31,27 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import List, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ArchConfig
+
+_BF16 = np.dtype(jnp.bfloat16)
+
+
+def widen(bits: np.ndarray, out: np.ndarray) -> np.ndarray:
+    """Write the KV values ``bits`` into the float32 array ``out``; returns it.
+
+    ``bits`` is the uint16 view of a bfloat16 array: a bf16 value is the
+    upper half of the float32 of the same value, so the widen is an exact
+    integer shift in one numpy loop. Any other dtype is cast.
+    """
+    if bits.dtype == np.uint16:
+        np.left_shift(bits, np.uint32(16), out=out.view(np.uint32),
+                      dtype=np.uint32)
+    else:
+        np.copyto(out, bits)
+    return out
 
 
 def _merge_partials(parts: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray]]):
@@ -44,12 +69,17 @@ def _merge_partials(parts: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray]]):
 class HostAttention:
     """Paged decode attention over the host KV pool.
 
-    ``pool_k`` / ``pool_v``: float32 numpy, shape [L, P, page, KV, hd]
-    (the ``PagePool(backend="host")`` arrays).
+    ``pool_k`` / ``pool_v``: numpy, shape [L, P, page, KV, hd] (the
+    ``PagePool(backend="host")`` arrays, or a kv-head slice of them under
+    TP): bfloat16 for 16-bit architectures, float32 otherwise. Reads widen
+    bf16 to float32 in per-thread scratch; a float32 pool is read as it is.
+
+    Decode work is split into one task per (row, block of at most
+    ``split_pages`` pages), dispatched over ``threads`` workers.
     """
 
     def __init__(self, cfg: ArchConfig, pool_k: np.ndarray, pool_v: np.ndarray,
-                 threads: int = 1, split_pages: int = 32):
+                 threads: int = 1, split_pages: int = 64):
         self.cfg = cfg
         self.pool_k = pool_k
         self.pool_v = pool_v
@@ -59,6 +89,14 @@ class HostAttention:
         self._tp: Optional[ThreadPoolExecutor] = (
             ThreadPoolExecutor(max_workers=self.threads) if self.threads > 1 else None
         )
+        # what the tasks gather: the bf16 pool's raw bits (widened by
+        # ``widen``), else the pool itself
+        bf16 = pool_k.dtype == _BF16
+        self._src_k = pool_k.view(np.uint16) if bf16 else pool_k
+        self._src_v = pool_v.view(np.uint16) if bf16 else pool_v
+        # per-thread gather/widen buffers, reused across calls (lane threads
+        # and pool workers each get their own on first use)
+        self._scratch = threading.local()
         # instrumentation (perf-model calibration + paper §5.5 bandwidth study)
         # — lock-protected: batch-0's io_callback and the batch-1 lane may
         # run concurrently from different threads
@@ -73,47 +111,103 @@ class HostAttention:
         self._acct_lock = threading.Lock()
 
     # ------------------------------------------------------------------
-    def _row_attention(self, layer: int, q_row: np.ndarray, table: np.ndarray,
-                       n_tokens: int, window: int = 0) -> np.ndarray:
-        """One request row: q_row [H, hd]; table [n_pages]; attend over
-        ``n_tokens`` cached tokens (the new token must already be written)."""
-        H, hd = q_row.shape
-        KV = self.pool_k.shape[3]
-        qpk = H // KV
-        scale = 1.0 / np.sqrt(hd)
-        n_pages = -(-n_tokens // self.page)
-        start_tok = 0
-        if window and n_tokens > window:
-            start_tok = n_tokens - window
-        first_page = start_tok // self.page
+    def _buffers(self) -> Tuple[np.ndarray, ...]:
+        """This thread's (gather k, gather v, wide k, wide v) scratch, each
+        [split_pages, page, KV, hd]; the gather pair is None for a float32
+        pool, which is gathered straight into the wide pair."""
+        bufs = getattr(self._scratch, "bufs", None)
+        if bufs is None:
+            shape = (self.split_pages,) + self.pool_k.shape[2:]
+            raw = self._src_k.dtype != np.float32
+            bufs = (np.empty(shape, self._src_k.dtype) if raw else None,
+                    np.empty(shape, self._src_v.dtype) if raw else None,
+                    np.empty(shape, np.float32), np.empty(shape, np.float32))
+            self._scratch.bufs = bufs
+        return bufs
 
-        qg = q_row.reshape(KV, qpk, hd)
-        parts: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = []
-        for p0 in range(first_page, n_pages, self.split_pages):
-            p1 = min(p0 + self.split_pages, n_pages)
-            ids = table[p0:p1]
-            k = self.pool_k[layer, ids].reshape(-1, KV, hd)  # [T, KV, hd]
-            v = self.pool_v[layer, ids].reshape(-1, KV, hd)
-            lo, hi = p0 * self.page, min(p1 * self.page, n_tokens)
-            k, v = k[: hi - lo], v[: hi - lo]
-            with self._acct_lock:
-                self.bytes_read += k.nbytes + v.nbytes
-            s = np.einsum("kqd,tkd->kqt", qg, k, optimize=True) * scale  # [KV,qpk,T]
-            if lo < start_tok:
-                s[:, :, : start_tok - lo] = -np.inf
-            m = np.max(s, axis=-1)  # [KV, qpk]
-            e = np.exp(s - m[..., None])
-            l = np.sum(e, axis=-1)
-            acc = np.einsum("kqt,tkd->kqd", e, v, optimize=True)
-            parts.append((acc.reshape(H, hd), l.reshape(H), m.reshape(H)))
-        if not parts:
-            return np.zeros((H, hd), np.float32)
-        return _merge_partials(parts).astype(np.float32)
+    @staticmethod
+    def _gather(src: np.ndarray, ids: np.ndarray, raw: Optional[np.ndarray],
+                wide: np.ndarray) -> np.ndarray:
+        """Pages ``ids`` of one layer's ``src`` as float32 [n, page, KV, hd].
+
+        ``mode="clip"`` lets ``np.take`` write straight into the scratch (the
+        default mode copies ``out`` first, to leave it intact on an index
+        error); page ids come from the engine's own tables."""
+        n = len(ids)
+        if raw is None:
+            return np.take(src, ids, axis=0, out=wide[:n], mode="clip")
+        return widen(np.take(src, ids, axis=0, out=raw[:n], mode="clip"),
+                     wide[:n])
+
+    def _block(self, layer: int, qg: np.ndarray, ids: np.ndarray, lo: int,
+               hi: int, start_tok: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Flash partial of one row's queries ``qg`` [KV, qpk, hd] over its
+        tokens [lo, hi), held by pages ``ids``; tokens before ``start_tok``
+        are masked. Returns (acc [H, hd], l [H], m [H])."""
+        KV, qpk, hd = qg.shape
+        gk, gv, wk, wv = self._buffers()
+        k = self._gather(self._src_k[layer], ids, gk, wk)
+        v = self._gather(self._src_v[layer], ids, gv, wv)
+        k = k.reshape(-1, KV, hd)[: hi - lo]  # [T, KV, hd]
+        v = v.reshape(-1, KV, hd)[: hi - lo]
+        s = np.matmul(qg, k.transpose(1, 2, 0))  # [KV, qpk, T]
+        s *= 1.0 / np.sqrt(hd)
+        if lo < start_tok:
+            s[:, :, : start_tok - lo] = -np.inf
+        m = np.max(s, axis=-1)  # [KV, qpk]
+        np.subtract(s, m[..., None], out=s)
+        np.exp(s, out=s)
+        l = np.sum(s, axis=-1)
+        acc = np.matmul(s, v.transpose(1, 0, 2))  # [KV, qpk, hd]
+        H = KV * qpk
+        return acc.reshape(H, hd), l.reshape(H), m.reshape(H)
+
+    def _attend_rows(self, layer: int, q: np.ndarray, tables: np.ndarray,
+                     n_tokens: Sequence[int], window: int) -> np.ndarray:
+        """q [R, H, hd] float32; row i attends over its first ``n_tokens[i]``
+        cached tokens (the last ``window`` of them when set) -> [R, H, hd]."""
+        R, H, hd = q.shape
+        KV = self.pool_k.shape[3]
+        page, split = self.page, self.split_pages
+        qg = q.reshape(R, KV, H // KV, hd)
+        tasks: List[Tuple[int, int, int, int, int]] = []
+        tokens = 0
+        for i in range(R):
+            n = int(n_tokens[i])
+            start = n - window if window and n > window else 0
+            n_pages = -(-n // page)
+            for p0 in range(start // page, n_pages, split):
+                p1 = min(p0 + split, n_pages)
+                tasks.append((i, p0, p1, start, n))
+            tokens += n - (start // page) * page
+        with self._acct_lock:
+            self.bytes_read += (2 * tokens * KV * hd
+                                * self.pool_k.dtype.itemsize)
+
+        def run(task: Tuple[int, int, int, int, int]):
+            i, p0, p1, start, n = task
+            return self._block(layer, qg[i], tables[i][p0:p1], p0 * page,
+                               min(p1 * page, n), start)
+
+        if self._tp is not None and len(tasks) > 1:
+            parts = list(self._tp.map(run, tasks))
+        else:
+            parts = [run(t) for t in tasks]
+        by_row: List[list] = [[] for _ in range(R)]
+        for task, part in zip(tasks, parts):  # block order within each row
+            by_row[task[0]].append(part)
+        out = np.zeros((R, H, hd), np.float32)
+        for i, row_parts in enumerate(by_row):
+            if row_parts:
+                out[i] = _merge_partials(row_parts)
+        return out
 
     # ------------------------------------------------------------------
     def append_tokens(self, layer: int, rows: np.ndarray, k_new: np.ndarray,
                       v_new: np.ndarray, page_ids: np.ndarray, offsets: np.ndarray) -> None:
-        """Write one new KV token per (host) row into the host pool."""
+        """Write one new KV token per (host) row into the host pool, at the
+        pool's dtype (a bf16 pool stores the bits the device pool stores
+        for a device row)."""
         if len(rows) == 0:
             return
         self.pool_k[layer, page_ids, offsets] = k_new[rows]
@@ -140,19 +234,10 @@ class HostAttention:
         if len(host_rows) == 0:
             return out
         t0 = time.perf_counter()
-        self.append_tokens(layer, host_rows, k_new.astype(np.float32),
-                           v_new.astype(np.float32), page_ids, offsets)
-        q32 = q.astype(np.float32)
-
-        def work(i: int) -> None:
-            r = host_rows[i]
-            out[r] = self._row_attention(layer, q32[r], tables[i], int(lens[i]) + 1, window)
-
-        if self._tp is not None and len(host_rows) > 1:
-            list(self._tp.map(work, range(len(host_rows))))
-        else:
-            for i in range(len(host_rows)):
-                work(i)
+        self.append_tokens(layer, host_rows, k_new, v_new, page_ids, offsets)
+        out[host_rows] = self._attend_rows(
+            layer, q[host_rows].astype(np.float32), tables,
+            np.asarray(lens) + 1, window)
         with self._acct_lock:
             self.busy_time += time.perf_counter() - t0
         return out
@@ -190,15 +275,15 @@ class HostAttention:
                 continue
             npg = -(-T // self.page)
             ids = tables[b, :npg]
-            k = self.pool_k[layer, ids].reshape(-1, KV, hd)[:T]
-            v = self.pool_v[layer, ids].reshape(-1, KV, hd)[:T]
+            k_src = self._src_k[layer, ids].reshape(-1, KV, hd)[:T]
+            v_src = self._src_v[layer, ids].reshape(-1, KV, hd)[:T]
             with self._acct_lock:
-                # DRAM bytes at the POOL's dtype (f16 on 16-bit archs),
-                # before the f32 compute cast — same convention as the
-                # decode path's bytes_read
-                self.prefix_bytes_read += k.nbytes + v.nbytes
-            k = k.astype(np.float32)
-            v = v.astype(np.float32)
+                # DRAM bytes at the POOL's dtype (bf16 on 16-bit archs),
+                # before the f32 widen — same convention as the decode
+                # path's bytes_read
+                self.prefix_bytes_read += k_src.nbytes + v_src.nbytes
+            k = widen(k_src, np.empty(k_src.shape, np.float32))
+            v = widen(v_src, np.empty(v_src.shape, np.float32))
             qg = q[b].astype(np.float32).reshape(S, KV, qpk, hd)
             s = np.einsum("skqd,tkd->skqt", qg, k, optimize=True) * scale
             mb = np.max(s, axis=-1)  # [S, KV, qpk]
@@ -216,8 +301,5 @@ class HostAttention:
     def attend(self, layer: int, q: np.ndarray, tables: np.ndarray,
                n_tokens: np.ndarray, window: int = 0) -> np.ndarray:
         """Pure attention (no append): q [R,H,hd] -> [R,H,hd]."""
-        return np.stack([
-            self._row_attention(layer, q[i].astype(np.float32), tables[i],
-                                int(n_tokens[i]), window)
-            for i in range(q.shape[0])
-        ])
+        return self._attend_rows(layer, q.astype(np.float32), tables,
+                                 n_tokens, window)

@@ -57,13 +57,13 @@ class PagePool:
                 self.k = jax.device_put(self.k, sh)
                 self.v = jax.device_put(self.v, sh)
         else:
-            # Host pools honor the activation dtype's byte width: numpy has no
-            # bfloat16, so 16-bit archs store float16 (2 bytes/elt — the
-            # paper's PACPU streams fp16; sizing, swap accounting and the perf
-            # model all see the deployment byte counts).
-            np_dt = np.float32 if cfg.activation_dtype == "float32" else np.float16
-            self.k = np.zeros(shape, np_dt)
-            self.v = np.zeros(shape, np_dt)
+            # The host pool holds the device pool's dtype: 16-bit archs store
+            # bfloat16 (ml_dtypes' numpy dtype, 2 bytes/elt), so swaps and
+            # prefill placement move the device's bits without a cast and
+            # host rows read exactly the values device rows read; float32
+            # archs keep float32.  HostAttention widens bf16 per task.
+            self.k = np.zeros(shape, self.dtype)
+            self.v = np.zeros(shape, self.dtype)
         self._free: List[int] = list(range(num_pages))
         # Per-page reference counts (prefix-cache sharing): a page returns to
         # the free list only when its LAST reader releases it.  Unshared pages
@@ -200,8 +200,7 @@ class PagePool:
         """[L, n, page, KV, hd] numpy copies (device→host PCIe DMA analogue)."""
         idx = np.asarray(pages, np.int32)
         if self.backend == "device":
-            return (np.asarray(self.k[:, idx], np.float32),
-                    np.asarray(self.v[:, idx], np.float32))
+            return np.asarray(self.k[:, idx]), np.asarray(self.v[:, idx])
         return self.k[:, idx].copy(), self.v[:, idx].copy()
 
     def put_pages(self, pages: List[int], k_np: np.ndarray, v_np: np.ndarray) -> None:
@@ -268,10 +267,6 @@ class DualPool:
             req.location = "gpu" if to == "gpu" else "cpu"
             return
         k_np, v_np = src.read_pages(req.pages)
-        if to == "cpu":
-            # account PCIe traffic at the host pool's byte width
-            k_np = np.asarray(k_np, dst.k.dtype)
-            v_np = np.asarray(v_np, dst.v.dtype)
         new_pages = dst.alloc(len(req.pages))
         dst.put_pages(new_pages, k_np, v_np)
         src.free(req.pages)

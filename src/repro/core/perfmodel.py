@@ -128,9 +128,9 @@ class PerfModel:
         """Decode attention on the host over `kv_tokens` total cached tokens.
 
         Memory-bandwidth bound (§2.2): the host reads K+V once per step.
-        The host KV cache is 16-bit (the paper's PACPU kernel streams fp16;
-        this container's numpy pool is fp32 purely because numpy lacks bf16 —
-        sizing and timing model the deployment layout).
+        The host KV cache is 16-bit: the host pool stores the device's
+        bf16 (the paper's PACPU kernel streams fp16), so sizing and timing
+        count 2 bytes per element.
         """
         if kv_tokens <= 0:
             return 0.0

@@ -293,9 +293,8 @@ class TransferEngine:
         # backend device ops from a second thread would serialize behind the
         # decode graphs and stall the join).  The host-pool scatter — the
         # DRAM-side half of the PCIe move — runs on the worker.
-        host_dtype = host.k.dtype
-        k_np = np.asarray(dev.k[:, idx], host_dtype)
-        v_np = np.asarray(dev.v[:, idx], host_dtype)
+        k_np = np.asarray(dev.k[:, idx])
+        v_np = np.asarray(dev.v[:, idx])
         if tr is not None:
             tr.emit("swap", "swap.stage", t0, time.perf_counter(),
                     {"iter": self.trace_iter, "rid": req.rid,
@@ -438,9 +437,6 @@ class TransferEngine:
         nbytes = 0
         k_np, v_np = src_pool.read_pages(pages)
         new_pages = dst_pool.alloc(len(pages))
-        if dst == "cpu":
-            k_np = np.asarray(k_np, dst_pool.k.dtype)
-            v_np = np.asarray(v_np, dst_pool.v.dtype)
         dst_pool.put_pages(new_pages, k_np, v_np)
         if src != dst:  # PCIe crossing: account at the host pool's byte width
             host = self.pool.host

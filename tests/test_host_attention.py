@@ -1,39 +1,78 @@
 """HostAttention (the paper's PACPU CPU kernel, numpy flavour) vs the jnp
 paged-attention oracle, including the flash-decoding split and threading."""
 
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.core.host_attention import HostAttention
+from repro.core.host_attention import HostAttention, widen
 from repro.kernels.paged_decode.ops import paged_decode_attention
 
 
-def make_pool(rng, L, P, page, KV, hd):
-    k = rng.normal(size=(L, P, page, KV, hd)).astype(np.float32)
-    v = rng.normal(size=(L, P, page, KV, hd)).astype(np.float32)
+def make_pool(rng, L, P, page, KV, hd, dtype=np.float32):
+    k = rng.normal(size=(L, P, page, KV, hd)).astype(np.float32).astype(dtype)
+    v = rng.normal(size=(L, P, page, KV, hd)).astype(np.float32).astype(dtype)
     return k, v
 
 
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
 @pytest.mark.parametrize("threads", [1, 4])
 @pytest.mark.parametrize("split_pages", [1, 2, 32])
-def test_host_attention_matches_oracle(threads, split_pages, rng):
+def test_host_attention_matches_oracle(threads, split_pages, dtype, rng):
+    """A bf16 pool is compared against the oracle on its widened values."""
     cfg = get_smoke_config("qwen3-0.6b")
     L, P, page = 2, 16, cfg.kv_block_size
     KV, hd, H = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-    pk, pv = make_pool(rng, L, P, page, KV, hd)
+    pk, pv = make_pool(rng, L, P, page, KV, hd, dtype)
     ha = HostAttention(cfg, pk, pv, threads=threads, split_pages=split_pages)
     R = 5
     tables = rng.integers(0, P, size=(R, 4)).astype(np.int32)
     lens = rng.integers(1, 4 * page, size=(R,)).astype(np.int32)
     q = rng.normal(size=(R, H, hd)).astype(np.float32)
+    pk32, pv32 = pk.astype(np.float32), pv.astype(np.float32)
     for layer in range(L):
         out = ha.attend(layer, q, tables, lens)
         oracle = paged_decode_attention(
-            jnp.asarray(q), jnp.asarray(pk[layer]), jnp.asarray(pv[layer]),
+            jnp.asarray(q), jnp.asarray(pk32[layer]), jnp.asarray(pv32[layer]),
             jnp.asarray(tables), jnp.asarray(lens), impl="ref")
         np.testing.assert_allclose(out, np.asarray(oracle), rtol=1e-4, atol=1e-4)
+
+
+def test_widen_is_exact_on_bf16_bits(rng):
+    """The integer widen gives the float32 of every bf16 value, specials
+    included."""
+    x = np.concatenate([rng.normal(size=1000).astype(np.float32) * 1e3,
+                        np.asarray([0.0, -0.0, np.inf, -np.inf, 1e-40],
+                                   np.float32)]).astype(jnp.bfloat16)
+    out = widen(x.view(np.uint16), np.empty(x.shape, np.float32))
+    np.testing.assert_array_equal(out, x.astype(np.float32))
+    assert np.isnan(widen(np.asarray([np.nan], jnp.bfloat16).view(np.uint16),
+                          np.empty(1, np.float32))[0])
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_host_attention_row_bitwise_independent(dtype, rng):
+    """A row's output is bitwise the same for 1 or 8 threads and alone or
+    inside a 6-row call: each row merges its own blocks in a fixed order."""
+    cfg = get_smoke_config("qwen3-0.6b")
+    L, P, page = 1, 64, cfg.kv_block_size
+    KV, hd, H = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    pk, pv = make_pool(rng, L, P, page, KV, hd, dtype)
+    R, MP = 6, 12
+    tables = np.stack([rng.permutation(P)[:MP] for _ in range(R)]).astype(np.int32)
+    lens = rng.integers(1, MP * page, size=(R,)).astype(np.int32)
+    lens[3] = MP * page - 3  # the row under test spans several blocks
+    q = rng.normal(size=(R, H, hd)).astype(np.float32)
+    outs = []
+    for threads in (1, 8):
+        ha = HostAttention(cfg, pk, pv, threads=threads, split_pages=3)
+        outs.append(ha.attend(0, q, tables, lens)[3])
+        outs.append(ha.attend(0, q[3:4], tables[3:4], lens[3:4])[0])
+    for o in outs[1:]:
+        np.testing.assert_array_equal(o, outs[0])
 
 
 def test_host_attention_append_then_attend(rng):
@@ -70,6 +109,85 @@ def test_host_attention_append_then_attend(rng):
     assert ha.busy_time > 0 and ha.bytes_read > 0
 
 
+def test_concurrent_callers_share_workers_safely():
+    """Host lanes call one HostAttention at once: more caller threads than
+    cores, with a short switch interval, must each get the serial result
+    bit for bit (every thread owns its gather/widen scratch)."""
+    import sys
+    import threading
+
+    rng = np.random.default_rng(5)
+    cfg = get_smoke_config("qwen3-0.6b")
+    page, KV, hd, H = cfg.kv_block_size, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    pk, pv = make_pool(rng, 1, 48, page, KV, hd, jnp.bfloat16)
+    R, MP = 3, 8
+    tables = np.stack([rng.permutation(48)[:MP] for _ in range(R)]).astype(np.int32)
+    lens = rng.integers(1, MP * page, size=(R,)).astype(np.int32)
+    callers = 2 * (os.cpu_count() or 4)
+    qs = rng.normal(size=(callers, R, H, hd)).astype(np.float32)
+    want = [HostAttention(cfg, pk, pv, split_pages=2).attend(0, q, tables, lens)
+            for q in qs]
+    ha = HostAttention(cfg, pk, pv, threads=4, split_pages=2)
+    got = [None] * callers
+
+    def call(c):
+        for _ in range(5):
+            got[c] = ha.attend(0, qs[c], tables, lens)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=call, args=(c,)) for c in range(callers)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    for c in range(callers):
+        np.testing.assert_array_equal(got[c], want[c])
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_kv_head_shards_concat_to_whole(dtype, rng):
+    """TP host shards attend over kv-head slice views of one pool; their
+    outputs, concatenated along heads, equal the whole pool's bit for bit."""
+    cfg = get_smoke_config("qwen3-0.6b")
+    page, KV, hd, H = cfg.kv_block_size, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    pk, pv = make_pool(rng, 1, 16, page, KV, hd, dtype)
+    R = 3
+    tables = np.stack([rng.permutation(16)[:4] for _ in range(R)]).astype(np.int32)
+    lens = rng.integers(1, 4 * page, size=(R,)).astype(np.int32)
+    q = rng.normal(size=(R, H, hd)).astype(np.float32)
+    whole = HostAttention(cfg, pk, pv, split_pages=2).attend(0, q, tables, lens)
+    hs, ks = H // KV, 1
+    parts = [HostAttention(cfg, pk[:, :, :, s:s + ks], pv[:, :, :, s:s + ks],
+                           split_pages=2).attend(0, q[:, s * hs:(s + ks) * hs],
+                                                 tables, lens)
+             for s in range(KV)]
+    np.testing.assert_array_equal(np.concatenate(parts, axis=1), whole)
+
+
+def test_append_stores_device_bf16_bits(rng):
+    """A bf16 pool stores the appended token as the device pool does: the
+    float32 value rounded to bf16 by the same cast."""
+    cfg = get_smoke_config("qwen3-0.6b")
+    page, KV, hd, H = cfg.kv_block_size, cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
+    pk, pv = make_pool(rng, 1, 4, page, KV, hd, jnp.bfloat16)
+    ha = HostAttention(cfg, pk, pv)
+    k_new = rng.normal(size=(2, KV, hd)).astype(np.float32)
+    v_new = rng.normal(size=(2, KV, hd)).astype(np.float32)
+    ha.run_layer(0, rng.normal(size=(2, H, hd)).astype(np.float32), k_new,
+                 v_new, host_rows=np.asarray([1]), tables=np.asarray([[2]]),
+                 lens=np.asarray([4]), page_ids=np.asarray([2]),
+                 offsets=np.asarray([4]))
+    dev_k = np.asarray(jnp.asarray(k_new[1]).astype(jnp.bfloat16))
+    dev_v = np.asarray(jnp.asarray(v_new[1]).astype(jnp.bfloat16))
+    np.testing.assert_array_equal(pk[0, 2, 4].view(np.uint16), dev_k.view(np.uint16))
+    np.testing.assert_array_equal(pv[0, 2, 4].view(np.uint16), dev_v.view(np.uint16))
+
+
 def test_host_attention_window(rng):
     cfg = get_smoke_config("zamba2-7b")
     L, P, page = 1, 8, cfg.kv_block_size
@@ -92,17 +210,20 @@ def test_host_attention_window(rng):
     np.testing.assert_allclose(out[0], o, rtol=1e-4, atol=1e-4)
 
 
-def test_prefix_partials_merge_matches_prefix_attention(rng):
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_prefix_partials_merge_matches_prefix_attention(dtype, rng):
     """Zero-copy host serving oracle: host-computed prefix flash partials
     merged with the device's causal-suffix attention must equal the joint
-    softmax over [prefix, causal suffix] (attn_lib.prefix_attention)."""
+    softmax over [prefix, causal suffix] (attn_lib.prefix_attention), on
+    the pool's widened values."""
     from repro.models import attention as attn_lib
 
     cfg = get_smoke_config("qwen3-0.6b")
     L, P, page = 2, 16, cfg.kv_block_size
     KV, hd, H = cfg.num_kv_heads, cfg.head_dim, cfg.num_heads
-    pk, pv = make_pool(rng, L, P, page, KV, hd)
+    pk, pv = make_pool(rng, L, P, page, KV, hd, dtype)
     ha = HostAttention(cfg, pk, pv)
+    pk, pv = pk.astype(np.float32), pv.astype(np.float32)
     B, S = 3, 7
     tables = rng.integers(0, P, size=(B, 3)).astype(np.int32)
     # row 2 has NO prefix: the merge must reduce to pure causal attention
@@ -129,6 +250,8 @@ def test_prefix_partials_merge_matches_prefix_attention(rng):
             jnp.asarray(prefix_lens), jnp.asarray(k_new), jnp.asarray(v_new))
         np.testing.assert_allclose(np.asarray(merged), np.asarray(oracle),
                                    rtol=1e-4, atol=1e-4)
-    assert ha.prefix_bytes_read > 0  # in-place gather was accounted
+    # the in-place gather was accounted at the pool's byte width
+    assert ha.prefix_bytes_read == (L * 2 * int(prefix_lens.sum()) * KV * hd
+                                    * np.dtype(dtype).itemsize)
     assert ha.busy_time == 0.0  # and kept OUT of the decode-attn EWMA signal
 
