@@ -946,6 +946,12 @@ class NeoEngine:
                         - len(plan.decode_gpu) - len(plan.decode_cpu0))
         to_host: List[bool] = []
         deferred: List[Request] = []
+
+        def over_budget(r: Request) -> bool:
+            # the scheduler's rule: a step's first prefill may take the
+            # whole budget (NeoScheduler._prefill_fits)
+            room = token_budget if to_host else self.engine_cfg.max_batch_tokens
+            return r.suffix_len > room
         for r in plan.prefill:
             host = r in plan.prefill_to_host
             pool = self.pool.host if host else self.pool.device
@@ -959,7 +965,7 @@ class NeoEngine:
                 target = "cpu" if host else "gpu"
                 shared, cow, r.cached_len = self.prefix_cache.acquire(
                     r.prefill_tokens, target)
-                if r.suffix_len > token_budget:
+                if over_budget(r):
                     # the match shrank and the realized suffix no longer fits
                     # this batch's token budget: release the pins and defer
                     # to the next iteration.  retract_acquire unwinds the
@@ -992,7 +998,7 @@ class NeoEngine:
                         pool.free([cow])
                     self.prefix_cache.retract_acquire()
                     r.cached_len = 0
-                    if r.suffix_len > token_budget:
+                    if over_budget(r):
                         # the cold suffix (== full prefill) busts the token
                         # budget too: defer instead of overrunning the batch
                         self.prefix_cache.retract_lookup(len(r.prefill_tokens))

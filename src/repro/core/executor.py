@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import functools
 import time
+import weakref
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -75,6 +76,21 @@ def _bucket(n: int, minimum: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _weak(method, *first):
+    """``method``, a bound method, as an io_callback target that holds its
+    object weakly, with ``first`` put before the callback's arguments.
+
+    JAX's tracing caches keep every callback target of a traced graph for
+    the life of the process; a bound method there would keep the executor,
+    and through it the weights and the pools, on the device after its
+    engine is gone."""
+    ref = weakref.WeakMethod(method)
+
+    def call(*args):
+        return ref()(*first, *args)
+    return call
 
 
 def _suffix_bucket(reqs: List[Request]) -> int:
@@ -285,7 +301,7 @@ class PagedExecutor:
         ax = tp_axis()
         if ax is None:
             host_out = io_callback(
-                self._host_cb,
+                _weak(self._host_cb),
                 jax.ShapeDtypeStruct(q.shape, jnp.float32),
                 lidx, q, k, v,
                 ordered=True,
@@ -299,7 +315,7 @@ class PagedExecutor:
             # io_callback is not supported inside shard_map bodies.
             sidx = jax.lax.axis_index(ax)
             host_out = io_callback(
-                self._host_cb_tp,
+                _weak(self._host_cb_tp),
                 jax.ShapeDtypeStruct(q.shape, jnp.float32),
                 sidx, lidx, q, k, v,
                 ordered=False,
@@ -488,7 +504,7 @@ class PagedExecutor:
         other lane's graph.  One jit object per lane; jax retraces per row
         bucket."""
         model, cfg = self.model, self.cfg
-        cb = functools.partial(self._host_cb_lane, lane)
+        cb = _weak(self._host_cb_lane, lane)
 
         def layer(p: Params, kind: str, lidx, x, positions):
             q, k, v = self._layer_pre(p, x, positions)
@@ -917,7 +933,7 @@ class PagedExecutor:
 
         def fn(params, tokens, true_lens, prefix_lens):
             return model.prefill_with_host_prefix(
-                params, tokens, prefix_lens, prefix_cb=self._host_prefix_cb,
+                params, tokens, prefix_lens, prefix_cb=_weak(self._host_prefix_cb),
                 capacity=S, true_lens=true_lens,
             )
 
@@ -934,7 +950,7 @@ class PagedExecutor:
             with tp_body("model"):
                 return model.prefill_with_host_prefix(
                     params, tokens, prefix_lens,
-                    prefix_cb=self._host_prefix_cb_tp,
+                    prefix_cb=_weak(self._host_prefix_cb_tp),
                     capacity=S, true_lens=true_lens,
                 )
 

@@ -516,6 +516,15 @@ class NeoScheduler:
             bounds.append(min(prev + 1, hi))
         return bounds
 
+    def _prefill_fits(self, plan: BatchPlan, req: Request, budget: int) -> bool:
+        """Whether ``req``'s prefill fits the ``budget`` of tokens left in
+        batch-0.  A plan's first prefill may take the whole
+        ``max_batch_tokens``: the decode rows' tokens never leave a busy
+        engine, so a prompt as long as the budget would otherwise block the
+        queue's head for as long as any row decodes."""
+        room = budget if plan.prefill else self.engine_cfg.max_batch_tokens
+        return req.suffix_len <= room
+
     def _admission_control(self, pools: PoolView, st) -> None:
         """Reject queued prompts that can never fit any pool."""
         page = pools.page_size
@@ -583,7 +592,7 @@ class NeoScheduler:
         budget = cfg.max_batch_tokens - plan.batch0_tokens
         while st.waitq and len(plan.prefill) + len(plan.decode_rows) < cfg.max_requests:
             nxt = st.waitq[0]
-            if nxt.suffix_len > budget:
+            if not self._prefill_fits(plan, nxt, budget):
                 break
             pages = nxt.new_prefill_pages(page)  # cached full pages are shared
             # one-shot preference: a request the step-5 balancer bounced back
@@ -749,7 +758,7 @@ class NeoScheduler:
         while st.waitq and len(plan.prefill) + len(plan.decode_rows) < self.engine_cfg.max_requests:
             nxt = st.waitq[0]
             pages = nxt.new_prefill_pages(page)
-            if nxt.suffix_len > budget or not pools.device_take(pages):
+            if not self._prefill_fits(plan, nxt, budget) or not pools.device_take(pages):
                 break
             plan.prefill.append(st.waitq.popleft())
             budget -= nxt.suffix_len

@@ -1,6 +1,9 @@
 """End-to-end engine tests: NEO offloading must be bit-identical to the pure
 model (greedy), across policies, preemption, and journal recovery."""
 
+import gc
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -56,6 +59,27 @@ def test_engine_offloads_and_swaps(dense_setup, rng):
     assert all(r.state == RequestState.FINISHED for r in eng.requests.values())
     assert eng.stats.offloaded_decodes > 0, "tight device pool must offload"
     assert eng.pool.swap_bytes > 0
+
+
+def test_closed_engine_frees_weights_and_pools():
+    """A closed engine, once dropped, takes its weights and device pool with
+    it: JAX's tracing caches keep the fused graphs' host-attention callbacks
+    for the life of the process, and they must not pin the executor."""
+    cfg = get_smoke_config("qwen3-0.6b")
+    params = get_model(cfg).init(jax.random.key(3))
+    ecfg = EngineConfig(device_pool_pages=7, host_pool_pages=96,
+                        max_batch_tokens=64, policy="neo")
+    eng = NeoEngine(cfg, ecfg, params=params)
+    for n in (24, 30, 18):
+        eng.submit(list(range(1, n + 1)), 6)
+    eng.run_until_done(300)
+    assert eng.stats.offloaded_decodes > 0, "the host callbacks must have run"
+    held = [weakref.ref(a) for a in
+            jax.tree.leaves(params) + [eng.pool.device.k, eng.pool.device.v]]
+    eng.close()
+    del eng, params
+    gc.collect()
+    assert sum(r() is not None for r in held) == 0
 
 
 def test_engine_recompute_preemption(dense_setup, rng):

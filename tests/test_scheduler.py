@@ -198,6 +198,24 @@ def test_no_starvation():
     assert all(r.state == RequestState.FINISHED for r in reqs)
 
 
+@pytest.mark.parametrize("policy", ["neo", "gpu_only"])
+def test_prompt_of_the_whole_budget_prefills_beside_decode_rows(policy):
+    """A prompt as long as max_batch_tokens is prefilled while other rows
+    decode: counted against the decode rows' tokens, it would wait at the
+    queue's head for as long as any row decodes."""
+    s = make_scheduler(policy, device=64, host=256, max_tokens=256)
+    h = Harness(s, 64, 256)
+    s.add_request(Request(rid=0, prompt=[1] * 32, max_new_tokens=64,
+                          arrival_time=0.0))
+    s.add_request(Request(rid=1, prompt=[1] * 256, max_new_tokens=8,
+                          arrival_time=1.0))
+    first = h.run_iteration()
+    assert [r.rid for r in first.prefill] == [0]
+    second = h.run_iteration()
+    assert [r.rid for r in second.prefill] == [1]
+    assert [r.rid for r in second.decode_rows] == [0]
+
+
 def test_gpu_only_never_offloads_decode():
     s = make_scheduler("gpu_only")
     h = Harness(s, 64, 256)
