@@ -53,6 +53,7 @@ stopped; emitted tokens are never re-issued).
 from __future__ import annotations
 
 import copy
+import logging
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -76,6 +77,8 @@ from repro.core.transfer import TransferEngine
 from repro.models.api import get_model
 from repro.obs.tracer import SpanTracer
 
+_log = logging.getLogger(__name__)
+
 PAGED_FAMILIES = ("dense", "moe", "vlm")
 
 
@@ -92,6 +95,10 @@ class EngineStats:
     device_decodes: int = 0
     wall_time: float = 0.0
     host_busy_time: float = 0.0
+    # host attention's task kernel ("native" C or the "numpy" fallback;
+    # "" without a host tier) and the decode calls the native one served
+    host_attn_kernel: str = ""
+    host_attn_native_calls: int = 0
     # -- measured pipeline overlap (Fig. 5, realized) ----------------------
     # device_busy_time: wall time of prefill + batch-0 dispatches (the lane
     # batch-1 is supposed to hide under).
@@ -281,6 +288,9 @@ class NeoEngine:
         self._next_rid = 0
         self.requests: Dict[int, Request] = {}
         self.stats = EngineStats()
+        if self.host_attn is not None:
+            self.stats.host_attn_kernel = self.host_attn.kernel
+            _log.info("host attention kernel: %s", self.host_attn.kernel)
         # Structured tracing (repro.obs): off by default.  Every call site
         # guards on ``tracer is not None`` so the traced and untraced paths
         # run the same computation — greedy outputs are bitwise identical.
@@ -738,22 +748,14 @@ class NeoEngine:
             tr.instant("engine", "plan_adopt", {"dur": dur})
         return plan, False
 
-    def _host_busy_total(self) -> float:
-        """Host-attention busy seconds summed over the engine-level instance
+    def _host_attn_total(self, attr: str):
+        """``attr`` of host attention summed over the engine-level instance
         and the executor's per-shard instances (TP device-lane callbacks)."""
         if not self.host_attn:
-            return 0.0
-        t = self.host_attn.busy_time
+            return 0
+        t = getattr(self.host_attn, attr)
         for s in getattr(self.executor, "host_shards", []) or []:
-            t += s.busy_time
-        return t
-
-    def _host_prefix_busy_total(self) -> float:
-        if not self.host_attn:
-            return 0.0
-        t = self.host_attn.prefix_busy_time
-        for s in getattr(self.executor, "host_shards", []) or []:
-            t += s.prefix_busy_time
+            t += getattr(s, attr)
         return t
 
     # ------------------------------------------------------------------
@@ -764,8 +766,8 @@ class NeoEngine:
         t0 = time.perf_counter()
         now = self.clock if now is None else now
         self.clock = now
-        host_busy0 = self._host_busy_total()
-        prefix_busy0 = self._host_prefix_busy_total()
+        host_busy0 = self._host_attn_total("busy_time")
+        prefix_busy0 = self._host_attn_total("prefix_busy_time")
         dev_busy0 = self.stats.device_busy_time
         spec_busy0 = self.stats.spec_busy_time
         swap_busy0 = self.transfer.stats.busy_time if self.transfer else 0.0
@@ -816,8 +818,10 @@ class NeoEngine:
         self.stats.wall_time += t_iter
         host_busy = 0.0
         if self.host_attn:
-            host_busy = self._host_busy_total() - host_busy0
+            host_busy = self._host_attn_total("busy_time") - host_busy0
             self.stats.host_busy_time += host_busy
+            self.stats.host_attn_native_calls = self._host_attn_total(
+                "native_calls")
         if self.transfer:
             ts = self.transfer.stats
             self.stats.swap_out_bytes = ts.bytes_out
@@ -833,8 +837,9 @@ class NeoEngine:
                 device_busy=self.stats.device_busy_time - dev_busy0,
                 swap_busy=(self.transfer.stats.busy_time - swap_busy0)
                 if self.transfer else 0.0,
-                host_prefix_busy=(self._host_prefix_busy_total() - prefix_busy0)
-                / self.tp if self.host_attn else 0.0,
+                host_prefix_busy=(self._host_attn_total("prefix_busy_time")
+                                  - prefix_busy0) / self.tp
+                if self.host_attn else 0.0,
                 spec_busy=self.stats.spec_busy_time - spec_busy0,
                 pipelined=self.engine_cfg.pipeline and plan.mode != "serial",
             )

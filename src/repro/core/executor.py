@@ -213,7 +213,8 @@ class PagedExecutor:
         )
         if tr is not None:
             tr.emit("hostattn-b0", f"L{layer}", t0, time.perf_counter(),
-                    {"rows": int(st["host_rows"].size)})
+                    {"rows": int(st["host_rows"].size),
+                     "kernel": self.host.kernel})
         return out
 
     def _host_cb_tp(self, shard, layer, q, k_new, v_new):
@@ -245,7 +246,8 @@ class PagedExecutor:
         if tr is not None:
             tr.emit(f"hostattn-b0-s{shard}", f"L{layer}", t0,
                     time.perf_counter(),
-                    {"rows": int(st["host_rows"].size), "shard": shard})
+                    {"rows": int(st["host_rows"].size), "shard": shard,
+                     "kernel": self.host_shards[shard].kernel})
         return out
 
     # ------------------------------------------------------------------
@@ -493,7 +495,8 @@ class PagedExecutor:
         )
         if tr is not None:
             tr.emit(f"hostattn-lane{lane}", f"L{layer}", t0,
-                    time.perf_counter(), {"rows": int(st["host_rows"].size)})
+                    time.perf_counter(), {"rows": int(st["host_rows"].size),
+                                          "kernel": self.host.kernel})
         return out
 
     def _build_decode_lane(self, lane: int):

@@ -9,15 +9,19 @@ The paper's kernel properties we preserve:
   on the worker threads, and each row's partial softmax results are merged
   with the standard (m, l, acc) log-sum-exp combine in a fixed block order;
 * **bandwidth-first layout**: the host pool keeps the device's 16-bit bits,
-  a task gathers its pages with one ``np.take`` into per-thread scratch and
-  widens them to float32 there, so DRAM streams 2 bytes per element once;
-* **GQA aware**: scores and outputs are per-KV-head batched ``np.matmul``
-  (BLAS) over the head's query group.
+  and a task widens them to float32 as it reads them, so DRAM streams 2
+  bytes per element once;
+* **GQA aware**: each K and V row is read once for its whole query group.
 
-Every step of a task (gather, integer widen, BLAS, ``exp``) is a numpy loop
-that releases the GIL, so tasks overlap on the worker threads. A row's
-output depends only on its own blocks, so it is bitwise the same for any
-thread count and any other rows in the call.
+A task is one call of the native kernel (``host_attention.c``, built on
+first use with the system C compiler and called through ``ctypes``, which
+releases the GIL): it walks the block's pages once for K and once for V,
+widening each row in registers, with no BLAS and no scratch copy. Where no
+compiler is found or the build fails, the task is the numpy fallback
+(``np.take`` into per-thread scratch, an integer widen, two per-KV-head
+``np.matmul``). ``HostAttention.kernel`` says which one runs. A row's output
+depends only on its own blocks, so it is bitwise the same for any thread
+count and any other rows in the call.
 
 On a real TPU VM this module runs on the host cores next to the accelerator
 (the engine calls it through an ordered ``io_callback`` from inside the jitted
@@ -26,9 +30,18 @@ decode step); in this container it is the literal execution path.
 
 from __future__ import annotations
 
+import ctypes
+import functools
+import hashlib
+import logging
+import os
+import platform
+import subprocess
+import tempfile
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
+from pathlib import Path
 from typing import List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -37,6 +50,65 @@ import numpy as np
 from repro.config import ArchConfig
 
 _BF16 = np.dtype(jnp.bfloat16)
+_log = logging.getLogger(__name__)
+
+# The native kernel's source and the command that builds it. No -ffast-math:
+# its start-up code sets flush-to-zero for the whole process.
+_SRC = Path(__file__).with_suffix(".c")
+_CC = ("cc", "-O3", "-march=native", "-fno-math-errno", "-shared", "-fPIC")
+_P, _I = ctypes.c_void_p, ctypes.c_int64
+_RUN_ARGS = (
+    [_P, _P] + [_I] * 6  # k, v; k and v (page, token, head) element strides
+    + [ctypes.c_int] + [_I] * 4 + [ctypes.c_float]  # bf16, page, kv, g, hd, scale
+    + [_P, _P, _I]  # q, tables, tables' row length
+    + [_P, _I, _P, _P, _P, _P]  # tasks, their count, first, order, next, remaining
+    + [_P] * 4)  # acc, l, m, out
+
+
+def _cpu_model() -> str:
+    try:
+        with open("/proc/cpuinfo") as f:
+            return next((ln for ln in f if ln.startswith("model name")), "")
+    except OSError:
+        return ""
+
+
+def native_library() -> Optional[ctypes.CDLL]:
+    """The native kernel for this machine, built on first use; None where
+    it cannot be built or loaded."""
+    return _build(_CC)
+
+
+@functools.lru_cache(maxsize=None)
+def _build(cc: Tuple[str, ...]) -> Optional[ctypes.CDLL]:
+    """Compile ``_SRC`` with ``cc`` into the user's cache directory, keyed by
+    the source, the command and the CPU (``-march=native``), and load it.
+
+    The library is installed by an atomic rename, so processes that build at
+    once each load a whole file."""
+    src = _SRC.read_bytes()
+    key = hashlib.sha256(b"\0".join(
+        [src, " ".join(cc).encode(), platform.machine().encode(),
+         _cpu_model().encode()])).hexdigest()[:16]
+    try:
+        cache = Path.home() / ".cache" / "repro"
+        lib = cache / f"host_attention-{key}.so"
+        if not lib.exists():
+            cache.mkdir(parents=True, exist_ok=True)
+            with tempfile.TemporaryDirectory(dir=cache) as tmp:
+                out = Path(tmp) / lib.name
+                subprocess.run([*cc, "-o", str(out), str(_SRC)], check=True,
+                               capture_output=True, timeout=300)
+                os.replace(out, lib)
+        so = ctypes.CDLL(str(lib))
+    except (OSError, RuntimeError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", None) or b""
+        _log.warning("host attention: no native kernel (%s %s); using numpy",
+                     e, detail.decode(errors="replace")[-2000:])
+        return None
+    so.hattn_run.argtypes = _RUN_ARGS
+    so.hattn_run.restype = ctypes.c_int
+    return so
 
 
 def widen(bits: np.ndarray, out: np.ndarray) -> np.ndarray:
@@ -54,16 +126,18 @@ def widen(bits: np.ndarray, out: np.ndarray) -> np.ndarray:
     return out
 
 
-def _merge_partials(parts: Sequence[Tuple[np.ndarray, np.ndarray, np.ndarray]]):
-    """Combine flash partials [(acc [H,hd], l [H], m [H]), ...] -> out [H,hd]."""
-    m = np.max(np.stack([p[2] for p in parts]), axis=0)  # [H]
-    num = np.zeros_like(parts[0][0])
-    den = np.zeros_like(parts[0][1])
-    for acc, l, mp in parts:
-        corr = np.exp(mp - m)  # [H]
-        num += acc * corr[:, None]
-        den += l * corr
-    return num / np.maximum(den, 1e-30)[:, None]
+def _merge_partials(acc: np.ndarray, l: np.ndarray, m: np.ndarray,
+                    first: np.ndarray) -> np.ndarray:
+    """Combine flash partials acc [N, H, hd], l [N, H], m [N, H] of N tasks
+    into [R, H, hd]: row i owns tasks [first[i], first[i + 1]), in block
+    order, and every row owns at least one."""
+    counts = np.diff(first)
+    starts = first[:-1]
+    mx = np.maximum.reduceat(m, starts, axis=0)  # [R, H]
+    corr = np.exp(m - np.repeat(mx, counts, axis=0))  # [N, H]
+    num = np.add.reduceat(acc * corr[..., None], starts, axis=0)
+    den = np.add.reduceat(l * corr, starts, axis=0)
+    return num / np.maximum(den, 1e-30)[..., None]
 
 
 class HostAttention:
@@ -72,10 +146,16 @@ class HostAttention:
     ``pool_k`` / ``pool_v``: numpy, shape [L, P, page, KV, hd] (the
     ``PagePool(backend="host")`` arrays, or a kv-head slice of them under
     TP): bfloat16 for 16-bit architectures, float32 otherwise. Reads widen
-    bf16 to float32 in per-thread scratch; a float32 pool is read as it is.
+    bf16 to float32; a float32 pool is read as it is.
 
     Decode work is split into one task per (row, block of at most
-    ``split_pages`` pages), dispatched over ``threads`` workers.
+    ``split_pages`` pages), run on ``threads`` workers. The task kernel is
+    chosen once, here, from what the machine and the pool offer:
+    ``"native"`` where the C kernel builds and the pool is bf16 or float32
+    with contiguous head rows of a multiple of 16 elements, else ``"numpy"``.
+    The native workers take tasks off a shared counter, largest first, and
+    whoever finishes a row's last block merges the row; the numpy tasks go
+    through the pool one by one and the rows merge in one numpy pass.
     """
 
     def __init__(self, cfg: ArchConfig, pool_k: np.ndarray, pool_v: np.ndarray,
@@ -94,6 +174,16 @@ class HostAttention:
         bf16 = pool_k.dtype == _BF16
         self._src_k = pool_k.view(np.uint16) if bf16 else pool_k
         self._src_v = pool_v.view(np.uint16) if bf16 else pool_v
+        self._lib = None
+        if ((bf16 or pool_k.dtype == np.float32) and pool_v.dtype == pool_k.dtype
+                and pool_k.shape == pool_v.shape and pool_k.shape[4] % 16 == 0
+                and pool_k.strides[4] == pool_v.strides[4] == pool_k.itemsize):
+            self._lib = native_library()
+        # element strides of a page, a token and a kv head, K's then V's
+        self._strides = tuple(n // pool_k.itemsize for n in pool_k.strides[1:4]
+                              + pool_v.strides[1:4])
+        self.kernel = "numpy" if self._lib is None else "native"
+        self.native_calls = 0  # _attend_rows calls the native kernel served
         # per-thread gather/widen buffers, reused across calls (lane threads
         # and pool workers each get their own on first use)
         self._scratch = threading.local()
@@ -140,10 +230,11 @@ class HostAttention:
                      wide[:n])
 
     def _block(self, layer: int, qg: np.ndarray, ids: np.ndarray, lo: int,
-               hi: int, start_tok: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Flash partial of one row's queries ``qg`` [KV, qpk, hd] over its
-        tokens [lo, hi), held by pages ``ids``; tokens before ``start_tok``
-        are masked. Returns (acc [H, hd], l [H], m [H])."""
+               hi: int, start_tok: int, acc: np.ndarray, l: np.ndarray,
+               m: np.ndarray) -> None:
+        """numpy flash partial of one row's queries ``qg`` [KV, qpk, hd] over
+        its tokens [lo, hi), held by pages ``ids``; tokens before
+        ``start_tok`` are masked. Writes acc [H, hd], l [H] and m [H]."""
         KV, qpk, hd = qg.shape
         gk, gv, wk, wv = self._buffers()
         k = self._gather(self._src_k[layer], ids, gk, wk)
@@ -154,53 +245,113 @@ class HostAttention:
         s *= 1.0 / np.sqrt(hd)
         if lo < start_tok:
             s[:, :, : start_tok - lo] = -np.inf
-        m = np.max(s, axis=-1)  # [KV, qpk]
-        np.subtract(s, m[..., None], out=s)
+        mb = np.max(s, axis=-1)  # [KV, qpk]
+        np.subtract(s, mb[..., None], out=s)
         np.exp(s, out=s)
-        l = np.sum(s, axis=-1)
-        acc = np.matmul(s, v.transpose(1, 0, 2))  # [KV, qpk, hd]
         H = KV * qpk
-        return acc.reshape(H, hd), l.reshape(H), m.reshape(H)
+        l[:] = np.sum(s, axis=-1).reshape(H)
+        m[:] = mb.reshape(H)
+        acc[:] = np.matmul(s, v.transpose(1, 0, 2)).reshape(H, hd)
 
     def _attend_rows(self, layer: int, q: np.ndarray, tables: np.ndarray,
                      n_tokens: Sequence[int], window: int) -> np.ndarray:
         """q [R, H, hd] float32; row i attends over its first ``n_tokens[i]``
         cached tokens (the last ``window`` of them when set) -> [R, H, hd]."""
         R, H, hd = q.shape
-        KV = self.pool_k.shape[3]
+        P, KV = self.pool_k.shape[1], self.pool_k.shape[3]
+        if H % KV or hd != self.pool_k.shape[4]:
+            raise ValueError(f"q of {H} heads x {hd} does not fit a pool of "
+                             f"{KV} kv heads x {self.pool_k.shape[4]}")
         page, split = self.page, self.split_pages
-        qg = q.reshape(R, KV, H // KV, hd)
-        tasks: List[Tuple[int, int, int, int, int]] = []
+        tasks: List[Tuple[int, int, int, int, int]] = []  # row, p0, lo, hi, start
+        first = [0]  # row i owns tasks [first[i], first[i + 1])
         tokens = 0
         for i in range(R):
             n = int(n_tokens[i])
             start = n - window if window and n > window else 0
             n_pages = -(-n // page)
             for p0 in range(start // page, n_pages, split):
-                p1 = min(p0 + split, n_pages)
-                tasks.append((i, p0, p1, start, n))
+                tasks.append((i, p0, p0 * page, min((p0 + split) * page, n),
+                              start))
+            first.append(len(tasks))
             tokens += n - (start // page) * page
         with self._acct_lock:
             self.bytes_read += (2 * tokens * KV * hd
                                 * self.pool_k.dtype.itemsize)
-
-        def run(task: Tuple[int, int, int, int, int]):
-            i, p0, p1, start, n = task
-            return self._block(layer, qg[i], tables[i][p0:p1], p0 * page,
-                               min(p1 * page, n), start)
-
-        if self._tp is not None and len(tasks) > 1:
-            parts = list(self._tp.map(run, tasks))
-        else:
-            parts = [run(t) for t in tasks]
-        by_row: List[list] = [[] for _ in range(R)]
-        for task, part in zip(tasks, parts):  # block order within each row
-            by_row[task[0]].append(part)
         out = np.zeros((R, H, hd), np.float32)
-        for i, row_parts in enumerate(by_row):
-            if row_parts:
-                out[i] = _merge_partials(row_parts)
+        if not tasks:
+            return out
+        if tables.shape[1] < -(-max(int(n) for n in n_tokens) // page):
+            raise ValueError(f"tables of {tables.shape[1]} pages do not hold "
+                             f"{max(n_tokens)} tokens")
+        # page ids as np.take(mode="clip") reads them
+        tab = np.clip(tables, 0, P - 1).astype(np.int64)
+        N = len(tasks)
+        parts = (np.empty((N, H, hd), np.float32),  # acc, l, m of each task
+                 np.empty((N, H), np.float32), np.empty((N, H), np.float32))
+        first = np.asarray(first, np.int64)
+        if self._lib is not None:
+            self._run_native(layer, q, tab, np.asarray(tasks, np.int64), first,
+                             parts, out)
+        else:
+            self._run_numpy(layer, q, tab, tasks, parts)
+            rows = np.flatnonzero(np.diff(first))
+            out[rows] = _merge_partials(*parts, first[np.append(rows, R)])
         return out
+
+    def _workers(self, n_tasks: int) -> int:
+        return min(self.threads, n_tasks) if self._tp is not None else 1
+
+    def _run_numpy(self, layer: int, q: np.ndarray, tab: np.ndarray,
+                   tasks: List[Tuple[int, int, int, int, int]],
+                   parts: Tuple[np.ndarray, ...]) -> None:
+        """Each task's partial into ``parts``, one pool job per task."""
+        R, H, hd = q.shape
+        qg = q.reshape(R, self.pool_k.shape[3], -1, hd)
+        acc, l, m = parts
+
+        def run(j: int) -> None:
+            i, p0, lo, hi, start = tasks[j]
+            self._block(layer, qg[i], tab[i, p0:-(-hi // self.page)], lo, hi,
+                        start, acc[j], l[j], m[j])
+
+        if self._workers(len(tasks)) > 1:
+            for _ in self._tp.map(run, range(len(tasks))):
+                pass
+        else:
+            for j in range(len(tasks)):
+                run(j)
+
+    def _run_native(self, layer: int, q: np.ndarray, tab: np.ndarray,
+                    tasks: np.ndarray, first: np.ndarray,
+                    parts: Tuple[np.ndarray, ...], out: np.ndarray) -> None:
+        """All tasks and row merges in the C kernel: one ``hattn_run`` call
+        per worker, each taking tasks off a shared counter, largest first."""
+        R, H, hd = q.shape
+        KV = self.pool_k.shape[3]
+        q = np.ascontiguousarray(q, np.float32)
+        order = np.argsort(tasks[:, 2] - tasks[:, 3], kind="stable")
+        counters = np.zeros(1 + R, np.int64)  # next task, then each row's
+        counters[1:] = np.diff(first)  # unfinished tasks
+        pk, pv = self._src_k, self._src_v
+        args = (pk.ctypes.data + layer * pk.strides[0],
+                pv.ctypes.data + layer * pv.strides[0], *self._strides,
+                int(pk.dtype == np.uint16), self.page, KV, H // KV, hd,
+                1.0 / np.sqrt(hd), q.ctypes.data, tab.ctypes.data,
+                tab.shape[1], tasks.ctypes.data, len(tasks), first.ctypes.data,
+                order.ctypes.data, counters.ctypes.data,
+                counters.ctypes.data + counters.itemsize,
+                *(a.ctypes.data for a in parts), out.ctypes.data)
+        workers = self._workers(len(tasks))
+        if workers > 1:
+            rcs = list(self._tp.map(lambda _: self._lib.hattn_run(*args),
+                                    range(workers)))
+        else:
+            rcs = [self._lib.hattn_run(*args)]
+        if any(rcs):
+            raise RuntimeError(f"host attention kernel failed: {rcs}")
+        with self._acct_lock:
+            self.native_calls += 1
 
     # ------------------------------------------------------------------
     def append_tokens(self, layer: int, rows: np.ndarray, k_new: np.ndarray,

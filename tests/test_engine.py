@@ -59,6 +59,10 @@ def test_engine_offloads_and_swaps(dense_setup, rng):
     assert all(r.state == RequestState.FINISHED for r in eng.requests.values())
     assert eng.stats.offloaded_decodes > 0, "tight device pool must offload"
     assert eng.pool.swap_bytes > 0
+    # the host rows ran on the kernel the engine reports
+    assert eng.stats.host_attn_kernel == eng.host_attn.kernel
+    if eng.host_attn.kernel == "native":
+        assert eng.stats.host_attn_native_calls > 0
 
 
 def test_closed_engine_frees_weights_and_pools():
